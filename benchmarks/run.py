@@ -140,6 +140,9 @@ def main() -> None:
                     help="minimum current/baseline items_per_s ratio for "
                          "--compare (default 0.8)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     want = set(args.only.split(",")) if args.only else None
     all_rows: list = []
 

@@ -35,7 +35,12 @@ from repro.core.swag_base import (
     suffix_scan,
     tree_index,
 )
-from repro.kernels.ops_registry import combine_fn, identity_for, op_for_monoid
+from repro.kernels.ops_registry import (
+    combine_fn,
+    identity_for,
+    op_for_monoid,
+    resolve_interpret,
+)
 from repro.kernels.sliding_window.kernel import sliding_window_pallas
 from repro.kernels.suffix_scan.kernel import suffix_scan_pallas
 
@@ -152,9 +157,7 @@ class ChunkedStream:
             raise ValueError(f"window must be >= 1, got {window}")
         self.chunk = int(chunk) if chunk is not None else max(self.window, 256)
         self.op = op_for_monoid(monoid) if use_kernel else None
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        self.interpret = interpret
+        self.interpret = resolve_interpret(interpret)
         self.block_b = block_b
         self._jitted_pc = jax.jit(self._process_chunk_impl)
         self._full_masks: dict = {}

@@ -1134,32 +1134,40 @@ class EventTimeChunkedStream:
 
 
 def in_order_reference(monoid: Monoid, ts, xs, horizon):
-    """Eager per-element oracle: stable-sort by timestamp, then for each
-    element fold (left-to-right) everything with ``ts' > ts - horizon``.
+    """Per-element oracle: stable-sort by timestamp, then for each element
+    fold (left-to-right, older operand LEFT) everything with
+    ``ts' > ts - horizon``.
 
     Returns ``(sorted_ts, (T, B, ...) aggregates)`` — what a per-element
     :class:`TimestampedWindow` scan of the in-order stream emits, and what
     :meth:`EventTimeChunkedStream.stream` must reproduce whenever disorder
-    ≤ slack.  O(T · window) combines — a test oracle, not an engine.
+    ≤ slack.  Sorted row i's window is the run ``[lo_i, i]`` of the sorted
+    stream; every row runs its own fold ``acc = acc ⊗ x[lo_i + k]`` from
+    the identity, all rows side by side, one window offset ``k`` per step
+    (a row whose window is shorter keeps its acc).  O(T · window) combines
+    in O(window) dispatches — a test oracle, not an engine.
     """
     ts = np.asarray(ts)
     order = np.argsort(ts, kind="stable")
+    sts = ts[order]
+    T = int(sts.shape[0])
+    lo = np.searchsorted(sts, sts - horizon, side="right")
+    rows = np.arange(T)
     lifted = jax.vmap(jax.vmap(monoid.lift))(xs)
+    lifted = jax.tree.map(lambda a: a[jnp.asarray(order)], lifted)
     ident = monoid.identity()
-    batch = jax.tree.leaves(lifted)[0].shape[1]
-    ident_b = jax.tree.map(
-        lambda i: jnp.broadcast_to(jnp.asarray(i), (batch,) + jnp.asarray(i).shape),
-        ident,
+    acc = jax.tree.map(
+        lambda a, i: jnp.broadcast_to(jnp.asarray(i, a.dtype), a.shape),
+        lifted, ident,
     )
-    win: list = []
-    outs = []
-    for i in order:
-        win.append(i)
-        while win and ts[win[0]] <= ts[i] - horizon:
-            win.pop(0)
-        acc = ident_b
-        for j in win:
-            acc = monoid.combine(acc, tree_index(lifted, int(j)))
-        outs.append(acc)
-    stacked = jax.tree.map(lambda *rows: jnp.stack(rows, axis=0), *outs)
-    return ts[order], stacked
+
+    @jax.jit
+    def step(acc, x, take):
+        nxt = jax.vmap(monoid.combine)(acc, x)
+        return _where_rows(take, nxt, acc)
+
+    for k in range(int(np.max(rows - lo + 1, initial=0))):
+        src = lo + k
+        x = _take0(lifted, jnp.asarray(np.minimum(src, T - 1)))
+        acc = step(acc, x, jnp.asarray(src <= rows))
+    return sts, acc

@@ -75,6 +75,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import ooo_index, swag_base
 from repro.core.event_time import (
@@ -536,12 +537,11 @@ class KeyedWindowStore:
         use = self.use_seg_kernel
         if self.instrument_combines or not (use is None or use):
             return None
-        from repro.kernels.ops_registry import op_for_monoid
+        from repro.kernels.ops_registry import default_interpret, op_for_monoid
 
         op = op_for_monoid(self.monoid)
         if use is None:
-            return op if (op is not None
-                          and jax.default_backend() == "tpu") else None
+            return op if (op is not None and not default_interpret()) else None
         if op is None:
             raise ValueError(
                 "use_seg_kernel=True needs a scalar-op monoid "
@@ -1311,7 +1311,6 @@ class ShardedKeyedStore:
         donate: bool = True,
         **store_kwargs,
     ):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from repro.distributed.sharding import keyed_store_pspecs
@@ -1347,14 +1346,14 @@ class ShardedKeyedStore:
                     lambda _: P(axis),
                     jax.eval_shape(lambda x: jax.vmap(monoid.lift)(x), xs),
                 )
-                return shard_map(
+                return jax.shard_map(
                     local_update,
                     mesh=mesh,
                     in_specs=(specs, P(), P()) + (P(),) * len(rest),
                     out_specs=(specs, y_spec),
-                    # the batched-admission while_loop has no replication
+                    # the batched-admission while_loop has no varying-axes
                     # rule; every output is explicitly sharded anyway
-                    check_rep=False,
+                    check_vma=False,
                 )(st, keys, xs, *rest)
 
             if donate:
@@ -1367,19 +1366,24 @@ class ShardedKeyedStore:
         self._update_no_ts = build(False)
 
     def init_state(self) -> PyTree:
-        from jax.sharding import NamedSharding
+        """The (shards, ...)-stacked state, built IN PLACE: one jitted
+        program whose outputs are sharded over the mesh axis, so each device
+        materializes only its own shard (the whole stack never exists on
+        any single device)."""
+        from repro.distributed.sharding import make_shardings
 
-        one = self.store.init_state()
-        stacked = jax.tree.map(
-            lambda a_: jnp.broadcast_to(a_, (self.n_shards,) + a_.shape).copy(),
-            one,
+        n = self.n_shards
+
+        def build():
+            return jax.tree.map(
+                lambda a_: jnp.broadcast_to(a_, (n,) + a_.shape),
+                self.store.init_state(),
+            )
+
+        shardings = make_shardings(
+            self.mesh, self._pspecs(jax.eval_shape(build), self.axis)
         )
-        specs = self._pspecs(stacked, self.axis)
-        return jax.tree.map(
-            lambda a_, s: jax.device_put(a_, NamedSharding(self.mesh, s)),
-            stacked,
-            specs,
-        )
+        return jax.jit(build, out_shardings=shardings)()
 
     def update_chunk(self, state, keys, xs, ts=None, mask=None):
         """Returns ``(state, ys, owner)``: ``ys`` is (shards, C, ...) with
@@ -1401,9 +1405,9 @@ class ShardedKeyedStore:
         """Host-side compaction of sharded outputs: pick each row from its
         owning shard (the one cross-shard data movement, OUTSIDE the steady
         state)."""
-        owner = jnp.asarray(owner)
-        idx = jnp.arange(owner.shape[0])
-        return jax.tree.map(lambda a_: a_[owner, idx], ys)
+        owner = np.asarray(owner)
+        idx = np.arange(owner.shape[0])
+        return jax.tree.map(lambda a_: np.asarray(a_)[owner, idx], ys)
 
     def counters(self, state, *, per_shard: bool = False) -> dict:
         """MESH-WIDE store counters: ``n_live`` / ``n_evicted`` /
@@ -1445,11 +1449,14 @@ class ShardedKeyedStore:
 
         def collect():
             c = self.counters(get_state(), per_shard=True)
+            # the (shards,) arrays are sharded over the mesh: index them on
+            # the host (one transfer), not with a device gather
+            per = jax.device_get(c.pop("per_shard"))
             out = {}
             for key, (name, _, _) in series.items():
                 out[name] = c[key]
                 for i in range(self.n_shards):
-                    out[f'{name}{{shard="{i}"}}'] = c["per_shard"][key][i]
+                    out[f'{name}{{shard="{i}"}}'] = per[key][i]
             return out
 
         registry.register_collector(collect)

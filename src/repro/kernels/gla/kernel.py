@@ -88,7 +88,7 @@ def gla_pallas(
     *,
     chunk: int = 64,
     variant: str = "mamba",
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fused chunked GLA.  r,k,a: (B,T,H,K); v: (B,T,H,V) → (B,T,H,V).
 

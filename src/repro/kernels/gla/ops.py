@@ -12,6 +12,7 @@ import jax
 
 from repro.kernels.gla.kernel import gla_pallas
 from repro.kernels.gla.ref import gla_ref
+from repro.kernels.ops_registry import resolve_interpret
 
 
 def gla(
@@ -26,8 +27,7 @@ def gla(
     use_kernel: bool = True,
     interpret: bool | None = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if use_kernel:
         return gla_pallas(
             r, k, v, a, bonus_u, chunk=chunk, variant=variant,

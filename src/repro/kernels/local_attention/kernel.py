@@ -87,7 +87,7 @@ def local_attention_pallas(
     softcap: float = 0.0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Windowed causal attention.  q, k, v: (BH, T, D) with equal heads."""
     BH, T, D = q.shape

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from repro.kernels.local_attention.kernel import local_attention_pallas
 from repro.kernels.local_attention.ref import local_attention_ref
+from repro.kernels.ops_registry import resolve_interpret
 
 
 def local_attention(
@@ -27,8 +28,7 @@ def local_attention(
     block_q: int = 128,
     block_k: int = 128,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     B, Hq, T, D = q.shape
     Hkv = k.shape[1]
     if Hq % Hkv:

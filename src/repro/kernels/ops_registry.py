@@ -48,6 +48,19 @@ _COMBINE: dict[str, Callable[[jax.Array, jax.Array], jax.Array]] = {
 }
 
 
+def default_interpret() -> bool:
+    """Whether Pallas kernels run in interpret mode by default: everywhere
+    but a TPU backend (CPU tests execute the same kernel bodies in Python).
+    The ONE place this choice is made; the raw ``*_pallas`` kernels take
+    ``interpret`` explicitly."""
+    return jax.default_backend() != "tpu"
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """An explicit ``interpret`` flag, or :func:`default_interpret`."""
+    return default_interpret() if interpret is None else bool(interpret)
+
+
 def available_ops() -> list[str]:
     """Names of the elementwise ops every kernel supports."""
     return sorted(_COMBINE)

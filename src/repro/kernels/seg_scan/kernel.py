@@ -90,7 +90,7 @@ def seg_suffix_scan_pallas(
     op: str = "sum",
     block_b: int = 8,
     block_t: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Row-wise segmented inclusive suffix scan of (B, T) with monoid
     ``op``; ``flags`` (B, T) marks segment ENDS."""
@@ -172,7 +172,7 @@ def seg_prefix_scan_pallas(
     op: str = "sum",
     block_b: int = 8,
     block_t: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Row-wise segmented inclusive prefix scan of (B, T) with monoid
     ``op``; ``flags`` (B, T) marks segment STARTS.  Mirror of

@@ -5,6 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops_registry import resolve_interpret
 from repro.kernels.seg_scan.kernel import (
     seg_prefix_scan_pallas,
     seg_suffix_scan_pallas,
@@ -31,8 +32,7 @@ def seg_suffix_scan_op(
     flags = jnp.asarray(flags)
     if flags.shape != x.shape:
         flags = jnp.broadcast_to(flags, x.shape)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     lead = x.shape[:-1]
     x2 = x.reshape((-1, x.shape[-1]))
     f2 = flags.reshape((-1, x.shape[-1]))
@@ -64,8 +64,7 @@ def seg_prefix_scan_op(
     flags = jnp.asarray(flags)
     if flags.shape != x.shape:
         flags = jnp.broadcast_to(flags, x.shape)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     lead = x.shape[:-1]
     x2 = x.reshape((-1, x.shape[-1]))
     f2 = flags.reshape((-1, x.shape[-1]))

@@ -85,7 +85,7 @@ def sliding_window_pallas(
     window: int,
     op: str = "sum",
     block_b: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Dense sliding-window aggregation over the last axis of ``x`` (B, T)."""
     if x.ndim != 2:

@@ -12,12 +12,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops_registry import resolve_interpret
 from repro.kernels.sliding_window.kernel import sliding_window_pallas
 from repro.kernels.sliding_window.ref import sliding_window_ref
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def sliding_window_agg(
@@ -30,8 +27,7 @@ def sliding_window_agg(
     block_b: int = 8,
 ) -> jax.Array:
     """``y[..., t] = x[..., t-w+1] ⊗ … ⊗ x[..., t]`` along the last axis."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     lead = x.shape[:-1]
     x2 = x.reshape((-1, x.shape[-1]))
     if use_kernel:

@@ -55,7 +55,7 @@ def suffix_scan_pallas(
     op: str = "sum",
     block_b: int = 8,
     block_t: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Row-wise inclusive suffix scan of (B, T) with monoid ``op``."""
     if x.ndim != 2:

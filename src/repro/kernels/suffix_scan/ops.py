@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels.ops_registry import resolve_interpret
 from repro.kernels.suffix_scan.kernel import suffix_scan_pallas
 from repro.kernels.suffix_scan.ref import suffix_scan_ref
 
@@ -18,8 +19,7 @@ def suffix_scan(
     block_t: int = 256,
 ) -> jax.Array:
     """``y[..., t] = x[..., t] ⊗ … ⊗ x[..., T-1]`` along the last axis."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     lead = x.shape[:-1]
     x2 = x.reshape((-1, x.shape[-1]))
     if use_kernel:

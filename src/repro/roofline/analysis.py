@@ -25,16 +25,33 @@ PEAK_FLOPS_BF16 = 197e12
 HBM_BW = 819e9
 ICI_BW = 50e9
 
-# Effective streaming bandwidth per backend for the memory-bound cost
-# models below.  The CPU figure is the measured single-core effective
-# bandwidth of this container on large gather/scatter+scan patterns (NOT
-# peak DRAM bandwidth — XLA:CPU runs these single-threaded); TPU/GPU use
-# the device HBM figure.
-BACKEND_EFF_BW = {
-    "cpu": 2.0e9,
-    "tpu": HBM_BW,
-    "gpu": 600e9,
-}
+# Effective streaming bandwidth for the memory-bound cost models below.
+# The CPU figure is a measured single-core effective bandwidth on large
+# gather/scatter+scan patterns (NOT peak DRAM bandwidth — XLA:CPU runs
+# these single-threaded).  TPUs are keyed by ``device_kind`` with their
+# published HBM bandwidth (Google Cloud "TPU v5e": 819 GB/s).  A backend or
+# TPU kind missing here is an error, never a borrowed default.
+BACKEND_EFF_BW = {"cpu": 2.0e9}
+TPU_HBM_BW = {"TPU v5 lite": HBM_BW}
+
+
+def effective_bandwidth(backend: Optional[str] = None) -> tuple:
+    """``(backend, bytes/s)`` for the cost models; ``backend`` defaults to
+    JAX's.  Raises ``ValueError`` for a backend or TPU kind with no entry."""
+    import jax
+
+    if backend is None:
+        backend = jax.default_backend()
+    if backend == "tpu":
+        kind = jax.devices("tpu")[0].device_kind
+        if kind not in TPU_HBM_BW:
+            raise ValueError(f"no HBM bandwidth known for TPU kind {kind!r}; "
+                             f"have {sorted(TPU_HBM_BW)}")
+        return backend, TPU_HBM_BW[kind]
+    if backend not in BACKEND_EFF_BW:
+        raise ValueError(f"no effective bandwidth known for backend "
+                         f"{backend!r}; have {sorted(BACKEND_EFF_BW)} and tpu")
+    return backend, BACKEND_EFF_BW[backend]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -260,11 +277,7 @@ def keyed_update_cost(
     """
     import math
 
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
-    bw = BACKEND_EFF_BW.get(backend, BACKEND_EFF_BW["cpu"])
+    backend, bw = effective_bandwidth(backend)
     C = int(chunk)
     h = max(int(window) - 1, 0)
     lg_c = max(math.ceil(math.log2(max(C, 2))), 1)
@@ -339,11 +352,7 @@ def eventtime_release_cost(
     """
     import math
 
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
-    bw = BACKEND_EFF_BW.get(backend, BACKEND_EFF_BW["cpu"])
+    backend, bw = effective_bandwidth(backend)
     P = int(chunk)
     W = int(capacity)
     M = W + P

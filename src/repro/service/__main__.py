@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.service.config import ServiceConfig
 from repro.service.core import AnalyticsService
 from repro.service.http import ServiceHTTPServer
@@ -36,6 +37,7 @@ def main(argv=None) -> int:
     p.add_argument("--obs", action="store_true",
                    help="attach the metrics registry (GET /metrics)")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = ServiceConfig(
         monoid=args.monoid,

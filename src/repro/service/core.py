@@ -161,14 +161,21 @@ class AnalyticsService:
         return self
 
     def stop(self, drain: bool = True, timeout: float = 300.0) -> None:
+        """Stop the consumer.  ``drain=True`` first waits for every accepted
+        row to become queryable and raises ``TimeoutError`` if it cannot
+        (the consumer is stopped either way)."""
         if self._thread is None:
             return
-        if drain:
-            self.flush(timeout=timeout)
+        drained = self.flush(timeout=timeout) if drain else True
         self._stop_evt.set()
         self._wake.set()
         self._thread.join(timeout=timeout)
         self._thread = None
+        if not drained:
+            raise TimeoutError(
+                f"stop(drain=True): {self._pending_rows} accepted rows were "
+                f"still pending after {timeout}s"
+            )
 
     def flush(self, timeout: float = 300.0) -> bool:
         """Block until every accepted row is queryable (tests/benchmarks).

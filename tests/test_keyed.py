@@ -32,6 +32,7 @@ from repro.core.keyed import (
     KeyedWindowStore,
     seg_suffix_scan,
 )
+from repro.core.reference import per_key_horizon_reference, per_key_reference
 from repro.core.telemetry import KeyedTelemetry
 from repro.obs import counters as obs_counters
 
@@ -39,25 +40,8 @@ rng = np.random.default_rng(0)
 
 
 # ---------------------------------------------------------------------------
-# Oracle
+# Oracle helpers (the per-key oracles live in repro.core.reference)
 # ---------------------------------------------------------------------------
-
-
-def per_key_reference(monoid, keys, vals, window):
-    """Dict of per-key element lists; output = left-to-right fold of each
-    key's last min(window, seen) lifted elements."""
-    hist: dict = {}
-    outs = []
-    for k, v in zip(keys, vals):
-        h = hist.setdefault(int(k), [])
-        h.append(monoid.lift(v))
-        if len(h) > window:
-            h.pop(0)
-        acc = h[0]
-        for e in h[1:]:
-            acc = monoid.combine(acc, e)
-        outs.append(acc)
-    return jax.tree.map(lambda *rows: jnp.stack(rows), *outs)
 
 
 def _tree_equal(a, b):
@@ -596,6 +580,10 @@ _SUBPROCESS_SHARDED = textwrap.dedent(
     assert jnp.array_equal(y, ref)
     # per-shard states are genuinely sharded on the leading axis
     assert state["carry"].sharding.spec[0] == "data"
+    # init_state builds each shard on its own device (no stacked copy)
+    shards = state["carry"].addressable_shards
+    assert sorted(s.device.id for s in shards) == [0, 1, 2, 3]
+    assert all(s.data.shape[0] == 1 for s in shards)
     print("OK")
     """
 )
@@ -647,25 +635,6 @@ def test_sharded_keyed_store_4dev():
 # ---------------------------------------------------------------------------
 # Event-time horizon mode
 # ---------------------------------------------------------------------------
-
-
-def per_key_horizon_reference(monoid, keys, vals, ts, window, horizon):
-    """Timestamped dict oracle for ``horizon=`` mode: each key keeps its
-    last min(window, seen) (ts, lifted) pairs; output at row j folds — older
-    operand LEFT — only the retained pairs with ``ts' > ts[j] - horizon``."""
-    hist: dict = {}
-    outs = []
-    for k, v, t in zip(keys, vals, np.asarray(ts, np.float32)):
-        h = hist.setdefault(int(k), [])
-        h.append((float(t), monoid.lift(v)))
-        if len(h) > window:
-            h.pop(0)
-        acc = monoid.identity()
-        for tt, e in h:
-            if tt > float(t) - horizon:
-                acc = monoid.combine(acc, e)
-        outs.append(acc)
-    return jax.tree.map(lambda *rows: jnp.stack(rows), *outs)
 
 
 @pytest.mark.parametrize("name", ["sum_i32", "max_i32", "affine_i32", "m4"])

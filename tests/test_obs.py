@@ -373,3 +373,21 @@ def test_keyed_stream_attach_obs_end_to_end():
     assert snap["repro_keyed_rows_total"] == 96
     assert snap["repro_keyed_live_keys"] == 8  # slots saturated
     assert snap["repro_keyed_evictions_total"] > 0
+
+
+def test_roofline_bandwidth_refuses_unknown_backend():
+    """The cost models behind the stage spans never borrow the CPU figure
+    for a backend (or TPU kind) they have no bandwidth for."""
+    from repro.roofline.analysis import (
+        BACKEND_EFF_BW,
+        effective_bandwidth,
+        keyed_update_cost,
+    )
+
+    assert effective_bandwidth("cpu") == ("cpu", BACKEND_EFF_BW["cpu"])
+    assert keyed_update_cost(1024, 256, backend="cpu")["bw"] == 2.0e9
+    for backend in ("gpu", "metal"):
+        with pytest.raises(ValueError, match="no effective bandwidth"):
+            effective_bandwidth(backend)
+        with pytest.raises(ValueError):
+            keyed_update_cost(1024, 256, backend=backend)

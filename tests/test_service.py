@@ -208,6 +208,20 @@ def test_backpressure_sheds_when_consumer_stalled():
     assert codes == [200, 200, 503, 503]
 
 
+def test_stop_raises_when_drain_times_out():
+    """stop(drain=True) must not report a drain that never finished."""
+    cfg = ServiceConfig(**CFG)
+    svc = AnalyticsService(cfg)
+    svc._drain_once = lambda: False  # a consumer that never drains
+    svc.start()
+    assert svc.ingest("a", *_batches(1, n=16, seed=8)[0])[0] == 200
+    assert not svc.flush(timeout=0.05)
+    with pytest.raises(TimeoutError, match="still pending"):
+        svc.stop(drain=True, timeout=0.05)
+    assert svc._thread is None  # the consumer is stopped either way
+    svc.stop()  # idempotent once stopped
+
+
 def test_malformed_and_unknown():
     cfg = ServiceConfig(**CFG)
     with AnalyticsService(cfg) as svc:

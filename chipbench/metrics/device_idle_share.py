@@ -1,0 +1,9 @@
+"""``device_idle_share``: the share of the traced window in which no
+``XLA Ops`` event ran on the device, in percent (device trace)."""
+
+
+def read(rec):
+    tr = rec.get("trace")
+    if not tr or tr["window_s"] <= 0 or tr["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])

@@ -92,11 +92,14 @@ class BatchedSWAG:
             )
             return state
 
-        self._insert = jax.vmap(lambda s, v: algo.insert(monoid, s, v))
-        self._evict = jax.vmap(lambda s: algo.evict(monoid, s))
-        self._query = jax.vmap(lambda s: algo.query(monoid, s))
-        self._step = jax.vmap(_step)
-        self._size = jax.vmap(algo.size)
+        # jitted: an eager call of a bare vmap (or lax.cond / lax.scan over
+        # fresh closures) retraces, and the lax ones recompile, every time
+        self._insert = jax.jit(jax.vmap(lambda s, v: algo.insert(monoid, s, v)))
+        self._evict = jax.jit(jax.vmap(lambda s: algo.evict(monoid, s)))
+        self._query = jax.jit(jax.vmap(lambda s: algo.query(monoid, s)))
+        self._step = jax.jit(jax.vmap(_step))
+        self._size = jax.jit(jax.vmap(algo.size))
+        self._scans = {}  # window -> jitted per-element stream
 
     def init(self, batch: int) -> PyTree:
         return jax.vmap(lambda _: self.algo.init(self.monoid, self.capacity))(
@@ -160,17 +163,23 @@ class BatchedSWAG:
         if chunked:
             return self._stream_chunked(state, xs, window, chunk)
 
-        def scan_step(st, x):
-            st = self._insert(st, x)
-            st = self._step(
-                st,
-                x,
-                jnp.zeros(self._size(st).shape, bool),
-                self._size(st) > window,
-            )
-            return st, self._query(st)
+        scan = self._scans.get(window)
+        if scan is None:
 
-        return jax.lax.scan(scan_step, state, xs)
+            def scan_step(st, x):
+                st = self._insert(st, x)
+                st = self._step(
+                    st,
+                    x,
+                    jnp.zeros(self._size(st).shape, bool),
+                    self._size(st) > window,
+                )
+                return st, self._query(st)
+
+            scan = self._scans[window] = jax.jit(
+                lambda st, xs: jax.lax.scan(scan_step, st, xs)
+            )
+        return scan(state, xs)
 
     def _concrete_sizes(self, state: PyTree):
         try:

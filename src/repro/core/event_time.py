@@ -190,12 +190,35 @@ def fold_axis0(monoid: Monoid, tree_arr: PyTree) -> PyTree:
                 ident,
             )
             n += 1
+        # pair rows with a reshape, not ``a[0::2]``: jnp lowers a strided
+        # index to a gather, and XLA:CPU fuses the gathers of every fold
+        # round into kernels whose compile time explodes with the depth
+        pairs = jax.tree.map(lambda a: a.reshape((n // 2, 2) + a.shape[1:]), tree_arr)
         tree_arr = monoid.combine(
-            jax.tree.map(lambda a: a[0::2], tree_arr),
-            jax.tree.map(lambda a: a[1::2], tree_arr),
+            jax.tree.map(lambda a: a[:, 0], pairs),
+            jax.tree.map(lambda a: a[:, 1], pairs),
         )
         n //= 2
     return tree_index(tree_arr, 0)
+
+
+def fold_values(monoid: Monoid, values, mask) -> PyTree:
+    """``lift(v_0) ⊗ … ⊗ lift(v_{N-1})`` over the rows of ``values`` where
+    ``mask`` holds; masked rows fold as the identity.  A monoid with a
+    ``lift_batch`` builds it in one pass (the KLL sketch: one sort instead
+    of N - 1 sketch merges); any other lifts, masks and :func:`fold_axis0`.
+    """
+    if monoid.lift_batch is not None:
+        return monoid.lift_batch(values, mask)
+    lifted = jax.vmap(monoid.lift)(values)
+    lifted = jax.tree.map(
+        lambda a, i: jnp.where(
+            mask.reshape((-1,) + (1,) * (a.ndim - 1)), a, jnp.asarray(i, a.dtype)
+        ),
+        lifted,
+        monoid.identity(),
+    )
+    return fold_axis0(monoid, lifted)
 
 
 # ---------------------------------------------------------------------------

@@ -46,6 +46,10 @@ class Monoid:
         removes the *front* element from a window aggregate:
         ``inverse_front(lift(e0) ⊗ r, lift(e0)) == r``.  Only defined for
         invertible monoids (used by the subtract-on-evict baseline).
+      lift_batch: optional (values (N,), mask (N,) bool) -> Agg, the fold
+        of ``lift`` over the masked values built in one pass; equal to the
+        fold up to the monoid's own reassociation error.  Read through
+        :func:`repro.core.event_time.fold_values`.
     """
 
     name: str
@@ -56,6 +60,7 @@ class Monoid:
     commutative: bool = False
     invertible: bool = False
     inverse_front: Optional[Callable[[PyTree, PyTree], PyTree]] = None
+    lift_batch: Optional[Callable[[Any, Any], PyTree]] = None
 
     def __repr__(self) -> str:  # keep pytest parametrize ids short
         return f"Monoid({self.name})"
@@ -490,51 +495,112 @@ def kll_monoid(
         items = jnp.full((L, kk), inf, dtype).at[0, 0].set(jnp.asarray(e, dtype))
         return {"items": items, "n": jnp.zeros((L,), jnp.int32).at[0].set(1)}
 
+    def compact(merged, n, off):
+        """One level's compaction of ``merged`` (ascending, +inf pads last,
+        ``n`` real items; even length >= k): slices and selects only.
+
+        Overflow (n > k) compacts the WHOLE level: every sorted adjacent
+        pair collapses to one survivor promoted at double weight — the
+        ``off`` member of the pair — and an odd item out (the largest)
+        stays.  Returns ``(kept (..., k), kept_n, promoted, pairs)``."""
+        pairs = jnp.where(n > kk, n // 2, 0)
+        pos = jnp.arange(merged.shape[-1])
+        idxk = pos[:kk]
+        two = merged.reshape(merged.shape[:-1] + (merged.shape[-1] // 2, 2))
+        promoted = jnp.where(off[..., None] == 1, two[..., 1], two[..., 0])
+        promoted = jnp.where(pos[: two.shape[-2]] < pairs[..., None], promoted, inf)
+        kept_n = n - 2 * pairs
+        largest = jnp.max(jnp.where(pos < n[..., None], merged, -inf), axis=-1)
+        kept = jnp.where(
+            (pairs > 0)[..., None],
+            jnp.where((idxk == 0) & (kept_n[..., None] > 0), largest[..., None], inf),
+            jnp.where(idxk < n[..., None], merged[..., :kk], inf),
+        )
+        return kept, kept_n, promoted, pairs
+
     def combine(a, b):
-        # carry = items promoted from the level below (weight already 2**l)
-        carry = jnp.full(a["items"].shape[:-2] + (2 * kk,), inf, dtype)
-        carry_n = jnp.zeros(a["n"].shape[:-1], jnp.int32)
         # survivor parity alternates with the global count (and level), so
         # repeated compactions do not systematically keep the larger (or
         # smaller) of each pair — the classic KLL de-biasing coin, made
         # deterministic
         tot = a["n"].sum(axis=-1) + b["n"].sum(axis=-1)
-        out_items, out_n = [], []
-        idx2k = jnp.arange(2 * kk)
-        idxk = jnp.arange(kk)
-        for l in range(L):
+
+        def level(carry, x):
+            # carry = items promoted from the level below (weight already 2**l)
+            promoted, promoted_n = carry
+            a_items, b_items, a_n, b_n, l = x
             merged = jnp.sort(
-                jnp.concatenate(
-                    [a["items"][..., l, :], b["items"][..., l, :], carry], axis=-1
-                ),
-                axis=-1,
+                jnp.concatenate([a_items, b_items, promoted], axis=-1), axis=-1
             )  # (..., 4k) ascending, +inf pads last
-            n = a["n"][..., l] + b["n"][..., l] + carry_n
-            # overflow compacts the WHOLE level: every sorted adjacent pair
-            # collapses to one survivor promoted at double weight
-            pairs = jnp.where(n > kk, n // 2, 0)
-            off = (tot + l) & 1
-            psrc = jnp.clip(2 * idx2k + off[..., None], 0, 4 * kk - 1)
-            promoted = jnp.where(
-                idx2k < pairs[..., None],
-                jnp.take_along_axis(merged, psrc, axis=-1),
-                inf,
-            )
-            ksrc = jnp.clip(2 * pairs[..., None] + idxk, 0, 4 * kk - 1)
-            kept_n = n - 2 * pairs
-            kept = jnp.where(
-                idxk < kept_n[..., None],
-                jnp.take_along_axis(merged, ksrc, axis=-1),
-                inf,
-            )
-            out_items.append(kept)
-            out_n.append(kept_n)
-            carry, carry_n = promoted, pairs
+            n = a_n + b_n + promoted_n
+            kept, kept_n, promoted, promoted_n = compact(merged, n, (tot + l) & 1)
+            return (promoted, promoted_n), (kept, kept_n)
+
+        # ONE traced level body scanned over the levels: the program holds
+        # one sort and one compaction per combine, not ``levels`` of each
+        carry0 = (
+            jnp.full(a["items"].shape[:-2] + (2 * kk,), inf, dtype),
+            jnp.zeros(a["n"].shape[:-1], jnp.int32),
+        )
+        xs = (
+            jnp.moveaxis(a["items"], -2, 0),
+            jnp.moveaxis(b["items"], -2, 0),
+            jnp.moveaxis(a["n"], -1, 0),
+            jnp.moveaxis(b["n"], -1, 0),
+            jnp.arange(L, dtype=jnp.int32),
+        )
+        _, (items, n) = jax.lax.scan(level, carry0, xs)
         # promotions past the top level fall off (capacity ~ k * 2**levels)
-        return {
-            "items": jnp.stack(out_items, axis=-2),
-            "n": jnp.stack(out_n, axis=-1),
-        }
+        return {"items": jnp.moveaxis(items, 0, -2), "n": jnp.moveaxis(n, 0, -1)}
+
+    def halve(x, off):
+        """The ``off`` member of each adjacent pair of ``x`` (1-D)."""
+        two = jnp.pad(x, (0, x.shape[0] % 2), constant_values=inf).reshape(-1, 2)
+        return jnp.where(off == 1, two[:, 1], two[:, 0])
+
+    def lift_batch(values, mask):
+        # ONE sort of the batch, then up the levels: keep the k largest
+        # items (k - 1 when the rest is odd) and compact the rest, on
+        # ever-halving slices — O(N) work, no chain of combines, and every
+        # level filled, so the capacity is combine's k * (2**levels - 1).
+        # The largest stay at the finest levels because the upper tail
+        # (p95, p99) is what a latency or value summary is read for.  Up to
+        # k items it equals the fold of the lifted values bit-exactly (one
+        # sorted level 0).  The parity coin starts from the item count.
+        # Work runs on negated values: ascending there is descending here,
+        # with the +inf pads still last.
+        cur = jnp.sort(jnp.where(mask, -jnp.asarray(values, dtype), inf))
+        n = tot = jnp.sum(mask, dtype=jnp.int32)
+        idxk = jnp.arange(kk)
+
+        def ascending(neg, kept_n):  # a stored level from negated items
+            return jnp.sort(jnp.where(idxk < kept_n, -neg, inf))
+
+        out_items, out_n = [], []
+        for l in range(L - 1):
+            # width >= k + 1 with an odd tail past k: both pairings below
+            # then yield the same number of pairs
+            width = max(kk + 1, cur.shape[0])
+            width += 1 - (width - kk) % 2
+            cur = jnp.pad(cur, (0, width - cur.shape[0]), constant_values=inf)
+            kept_n = jnp.where(n > kk, kk - ((n - kk) & 1), n)
+            out_items.append(ascending(cur[:kk], kept_n))
+            out_n.append(kept_n)
+            off = (tot + l) & 1
+            promoted = jnp.where(
+                kept_n == kk, halve(cur[kk:], off), halve(cur[kk - 1:], off)
+            )
+            n = (n - kept_n) // 2
+            cur = jnp.where(jnp.arange(promoted.shape[0]) < n, promoted, inf)
+        # past capacity the top level keeps an even spread of its items
+        # (combine drops a compaction's survivors there: mass is lost either
+        # way, but no tail is cut off)
+        cur = jnp.pad(cur, (0, max(kk - cur.shape[0], 0)), constant_values=inf)
+        spread = jnp.where(n > kk, (2 * idxk + 1) * n // (2 * kk), idxk)
+        kept_n = jnp.minimum(n, kk)
+        out_items.append(ascending(cur[spread], kept_n))
+        out_n.append(kept_n)
+        return {"items": jnp.stack(out_items), "n": jnp.stack(out_n)}
 
     def lower(v):
         return kll_quantiles(v, qs)
@@ -542,11 +608,14 @@ def kll_monoid(
     return Monoid(
         name=f"kll{kk}x{L}",
         identity=identity,
-        combine=combine,
+        # jitted so an eager call reuses one compiled scan per operand shape
+        # (an eager lax.scan retraces and recompiles on every call)
+        combine=jax.jit(combine),
         lift=lift,
         lower=lower,
         commutative=True,
         invertible=False,
+        lift_batch=lift_batch,
     )
 
 

@@ -24,6 +24,7 @@ head: shape (H, 1, 1).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -84,6 +85,10 @@ class WindowedStateCell:
         # capacity = window + 1: ring slack for the insert-then-evict step.
         return daba_lite.init(self.monoid, self.window + 1)
 
+    # jitted with the (frozen, hashable) cell static: an eager call then
+    # reuses one compiled step, where an eager lax.cond over fresh lambdas
+    # traces and compiles both branches again on every token
+    @functools.partial(jax.jit, static_argnums=0)
     def update(self, state: PyTree, decay: jax.Array, update: jax.Array):
         m = self.monoid
         state = daba_lite.insert(m, state, {"d": decay, "u": update})
@@ -157,6 +162,7 @@ class ChunkedWindowedStateCell:
             "count": jnp.zeros((), jnp.int32),  # tokens in partial chunk
         }
 
+    @functools.partial(jax.jit, static_argnums=0)  # as WindowedStateCell
     def update(self, state: PyTree, decay: jax.Array, update: jax.Array):
         m = self.monoid
         partial = m.combine(state["partial"], {"d": decay, "u": update})

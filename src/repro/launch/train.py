@@ -41,6 +41,7 @@ def main():
     from repro.configs import ARCHS
     from repro.data.stream import SyntheticStream
     from repro.distributed.sharding import make_shardings, param_pspecs
+    from repro.launch.mesh import make_mesh
     from repro.models.factory import reduced_config
     from repro.optim.adamw import AdamW, warmup_cosine
     from repro.train.trainer import Trainer, TrainerConfig
@@ -48,7 +49,7 @@ def main():
     cfg = ARCHS[args.arch] if args.full else reduced_config(ARCHS[args.arch])
     d_data, d_model = (int(x) for x in args.mesh.split("x"))
     if d_data * d_model > 1:
-        mesh = jax.make_mesh((d_data, d_model), ("data", "model"))
+        mesh = make_mesh((d_data, d_model), ("data", "model"))
         # reduced configs need kv heads divisible by the model axis
         if cfg.num_kv_heads % d_model and cfg.num_kv_heads < d_model:
             cfg = dataclasses.replace(cfg, num_kv_heads=cfg.num_heads)

@@ -157,25 +157,13 @@ class KLLHistogram:
         fn = self._drain_jits.get(n)
         if fn is None:
             import jax
-            import jax.numpy as jnp
 
-            from repro.core.event_time import fold_axis0
+            from repro.core.event_time import fold_values
 
             m = self._m
 
             def drain(agg, vals, mask):
-                lifted = jax.vmap(m.lift)(vals)
-                ident = m.identity()
-                lifted = jax.tree.map(
-                    lambda a, i: jnp.where(
-                        mask.reshape((-1,) + (1,) * (a.ndim - 1)),
-                        a,
-                        jnp.asarray(i, a.dtype),
-                    ),
-                    lifted,
-                    ident,
-                )
-                return m.combine(agg, fold_axis0(m, lifted))
+                return m.combine(agg, fold_values(m, vals, mask))
 
             fn = self._drain_jits[n] = jax.jit(drain)
         return fn

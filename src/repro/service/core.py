@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.event_time import fold_axis0
+from repro.core.event_time import fold_values
 from repro.core.keyed import KeyedChunkedStream
 from repro.core.monoids import (
     get_monoid,
@@ -119,7 +119,7 @@ class AnalyticsService:
                 "hot": topk_monoid(cfg.topk_k),
             }
             self._rollup = KeyedTelemetry(
-                {name: dataclasses.replace(m, lift=lambda a: a)
+                {name: dataclasses.replace(m, lift=lambda a: a, lift_batch=None)
                  for name, m in self._sketches.items()},
                 cfg.rollup_window,
                 slots=cfg.max_tenants,
@@ -363,27 +363,17 @@ class AnalyticsService:
         return True
 
     def _chunk_summary(self, keys, xs, mask):
-        """Reduce one drained chunk to a single product-sketch element:
-        a masked log-depth fold per sketch (C combines total) — the rollup
-        store then folds ONE element per chunk instead of C."""
-        out = {}
+        """Reduce one drained chunk to a single product-sketch element —
+        the rollup store then folds ONE element per chunk instead of C."""
         inputs = {
             "values": xs.astype(jnp.float32),
             "distinct": keys,
             "hot": keys,
         }
-        for name, m in self._sketches.items():
-            lifted = jax.vmap(m.lift)(inputs[name])
-            ident = m.identity()
-            lifted = jax.tree.map(
-                lambda a, i: jnp.where(
-                    mask.reshape((-1,) + (1,) * (a.ndim - 1)),
-                    a, jnp.asarray(i, a.dtype),
-                ),
-                lifted, ident,
-            )
-            out[name] = fold_axis0(m, lifted)
-        return out
+        return {
+            name: fold_values(m, inputs[name], mask)
+            for name, m in self._sketches.items()
+        }
 
     # -- query path --------------------------------------------------------
 

@@ -21,6 +21,10 @@ from repro.distributed.compression import (
 from repro.distributed.sharding import param_pspecs
 from repro.models import factory
 
+# a hung child fails its test here, inside the per-test limit
+# (conftest.TEST_LIMIT_S); each child takes ~20 s on an idle CPU host
+SUBPROCESS_S = 180
+
 
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_param_pspecs_divisible(arch):
@@ -119,7 +123,7 @@ _SUBPROCESS_COLLECTIVE = textwrap.dedent(
 def test_compressed_ring_allreduce_8dev():
     r = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_COLLECTIVE],
-        capture_output=True, text=True, timeout=300,
+        capture_output=True, text=True, timeout=SUBPROCESS_S,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
         cwd=__import__("os").path.dirname(__import__("os").path.dirname(__file__)),
     )
@@ -139,10 +143,11 @@ _SUBPROCESS_SHARDED_TRAIN = textwrap.dedent(
     from repro.optim.adamw import AdamW
     from repro.train.train_step import init_train_state, make_train_step
     from repro.distributed.sharding import param_pspecs, make_shardings
+    from repro.launch.mesh import make_debug_mesh
     import dataclasses
 
     cfg = dataclasses.replace(reduced_config(ARCHS["llama3.2-1b"]), num_kv_heads=4)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_debug_mesh(2, 2)
     opt = AdamW(learning_rate=1e-3)
     params = init_params(cfg, jax.random.key(0))
     state = init_train_state(cfg, params, opt, metric_window=8)
@@ -176,7 +181,7 @@ def test_sharded_train_step_matches_single_device():
     """DP×TP=2×2 sharded train step ≡ single-device step (loss/params)."""
     r = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_SHARDED_TRAIN],
-        capture_output=True, text=True, timeout=600,
+        capture_output=True, text=True, timeout=SUBPROCESS_S,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
         cwd=__import__("os").path.dirname(__import__("os").path.dirname(__file__)),
     )
@@ -234,7 +239,7 @@ def test_sharded_keyed_counters_rollup_4dev():
     (the pre-PR-8 blind spot: only shard-local scalars existed)."""
     r = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_SHARDED_COUNTERS],
-        capture_output=True, text=True, timeout=600,
+        capture_output=True, text=True, timeout=SUBPROCESS_S,
         env={**__import__("os").environ, "PYTHONPATH": "src"},
         cwd=__import__("os").path.dirname(__import__("os").path.dirname(__file__)),
     )

@@ -1,13 +1,13 @@
-"""Event-time windowing: engine ≡ in-order reference, watermark edge cases.
+"""Event-time engine ≡ in-order reference.
 
-The load-bearing property (the PR-3 tentpole acceptance): for ANY stream
-whose disorder is bounded by the engine's slack, the bulk out-of-order
-engine's released outputs equal — bit-exactly for integer/selection monoids,
-including NON-commutative ones — the per-element in-order scan of the
-timestamp-sorted stream.  Plus: watermark-driven bulk evictions
-(TimestampedWindow), late-data policies, capacity overflow detection, the
-range-fold primitive, and the DisorderedEventStream generator's lateness
-bound.
+The load-bearing property: for ANY stream whose disorder is bounded by
+the engine's slack, the bulk out-of-order engine's released outputs
+equal — bit-exactly for integer/selection monoids, including
+NON-commutative ones — the per-element in-order scan of the
+timestamp-sorted stream, across disorder levels, integer timestamps and
+the DisorderedEventStream generator.  The hypothesis sweep of the same
+property is test_event_time_property.py; engine edge cases (chunk splits,
+gaps, late chunks, continuation) are in test_event_time_windows.py.
 """
 
 import numpy as np
@@ -16,80 +16,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import daba_lite, monoids, swag_base
-from repro.core.chunked import ChunkedStream
-from repro.core.event_time import (
-    EventTimeChunkedStream,
-    TimestampedWindow,
-    flip_range_fold,
-    fold_axis0,
-    in_order_reference,
-    range_fold,
-    range_fold_invertible,
-)
+from repro.core import monoids
+from repro.core.event_time import EventTimeChunkedStream, in_order_reference
 from repro.data.stream import DisorderedEventStream
-from repro.obs import counters as obs_counters
 
-rng = np.random.default_rng(7)
-
-
-def _scalar_vals(shape, dtype=jnp.float32):
-    if jnp.issubdtype(dtype, jnp.integer):
-        return jnp.asarray(rng.integers(-9, 9, shape), dtype)
-    return jnp.asarray(rng.standard_normal(shape), dtype)
-
-
-def _int_float_vals(shape):  # integer-valued floats: m4/argmax stay bit-exact
-    return jnp.asarray(rng.integers(-9, 9, shape).astype(np.float32))
-
-
-def _affine_vals(shape):
-    return (
-        jnp.asarray(rng.integers(-5, 5, shape), jnp.int32),
-        jnp.asarray(rng.integers(-5, 5, shape), jnp.int32),
-    )
-
-
-def _argmax_vals(shape):
-    return (
-        _int_float_vals(shape),
-        jnp.asarray(rng.integers(0, 1000, shape), jnp.int32),
-    )
-
-
-# ≥ 2 NON-commutative monoids verified bit-exactly (affine_i32: exact
-# modular arithmetic; m4 + argmax: pure selection on integer-valued floats),
-# plus invertible-fast-path and float-allclose coverage.
-MONOID_CASES = {
-    "sum_i32": (monoids.sum_monoid(jnp.int32),
-                lambda s: _scalar_vals(s, jnp.int32), True),
-    "affine_i32": (monoids.affine_int_monoid(), _affine_vals, True),
-    "m4_int": (monoids.m4_monoid(), _int_float_vals, True),
-    "argmax": (monoids.argmax_monoid(), _argmax_vals, True),
-    "mean": (monoids.mean_monoid(), _scalar_vals, False),
-}
-
-
-def _assert_tree_close(a, b, exact, ctx=""):
-    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-        x, y = np.asarray(x), np.asarray(y)
-        if exact:
-            assert np.array_equal(x, y), (ctx, x, y)
-        else:
-            assert np.allclose(x, y, rtol=1e-4, atol=1e-4), (ctx, x, y)
-
-
-def _disordered(T, disorder, slack, *, seed, int_ts=False):
-    """(arrival_ts, arrival_order): lateness bounded by ``slack``."""
-    r = np.random.default_rng(seed)
-    if int_ts:
-        ts = np.sort(r.integers(0, 3 * T, T)).astype(np.int32)
-        delay = (r.random(T) < disorder) * r.integers(0, max(int(slack), 1), T)
-    else:
-        ts = np.sort(r.uniform(0, 2.0 * T, T)).astype(np.float32)
-        delay = (r.random(T) < disorder) * r.uniform(0, slack, T)
-    order = np.argsort(ts + delay, kind="stable")
-    return ts[order], order
+from event_time_cases import MONOID_CASES, assert_tree_close, disordered
 
 
 # ---------------------------------------------------------------------------
@@ -104,7 +35,7 @@ def test_engine_matches_in_order_reference(mname, disorder, slack):
     T, B, horizon = 70, 2, 17.0
     # deterministic per-case seed (str hash is randomized per process)
     seed = sum(map(ord, mname)) * 100 + int(disorder * 10)
-    ats, order = _disordered(T, disorder, slack, seed=seed)
+    ats, order = disordered(T, disorder, slack, seed=seed)
     xs = mk((T, B))
     axs = jax.tree.map(lambda a: a[order], xs)
     eng = EventTimeChunkedStream(
@@ -114,7 +45,7 @@ def test_engine_matches_in_order_reference(mname, disorder, slack):
     assert res.n_late == 0 and res.n_dropped == 0
     ref_ts, ref_ys = in_order_reference(m, ats, axs, horizon)
     assert np.array_equal(res.ts, ref_ts)
-    _assert_tree_close(res.ys, ref_ys, exact, (mname, disorder, slack))
+    assert_tree_close(res.ys, ref_ys, exact, (mname, disorder, slack))
 
 
 @pytest.mark.parametrize("mname", ["sum_i32", "affine_i32"])
@@ -122,7 +53,7 @@ def test_engine_integer_timestamps_bit_exact(mname):
     """Integer event times through the int32 sentinel arithmetic."""
     m, mk, _ = MONOID_CASES[mname]
     T, B = 60, 2
-    ats, order = _disordered(T, 0.4, 6, seed=11, int_ts=True)
+    ats, order = disordered(T, 0.4, 6, seed=11, int_ts=True)
     axs = jax.tree.map(lambda a: a[order], mk((T, B)))
     eng = EventTimeChunkedStream(
         m, 9, slack=6, chunk=13, capacity=64, buffer=16, ts_dtype=jnp.int32
@@ -130,23 +61,7 @@ def test_engine_integer_timestamps_bit_exact(mname):
     res = eng.stream(jnp.asarray(ats), axs)
     ref_ts, ref_ys = in_order_reference(m, ats, axs, 9)
     assert np.array_equal(res.ts, ref_ts)
-    _assert_tree_close(res.ys, ref_ys, exact=True, ctx=mname)
-
-
-def test_engine_ragged_chunks_and_tiny_chunk():
-    """Chunk sizes that straddle T unevenly (C ∤ T, C=1) stay exact."""
-    m, mk, _ = MONOID_CASES["affine_i32"]
-    T, B = 41, 1
-    ats, order = _disordered(T, 0.5, 7.0, seed=3)
-    axs = jax.tree.map(lambda a: a[order], mk((T, B)))
-    ref_ts, ref_ys = in_order_reference(m, ats, axs, 11.0)
-    for C in (1, 5, 64):
-        eng = EventTimeChunkedStream(
-            m, 11.0, slack=7.0, chunk=C, capacity=64, buffer=32
-        )
-        res = eng.stream(jnp.asarray(ats), axs)
-        assert np.array_equal(res.ts, ref_ts), C
-        _assert_tree_close(res.ys, ref_ys, exact=True, ctx=C)
+    assert_tree_close(res.ys, ref_ys, exact=True, ctx=mname)
 
 
 def test_disordered_event_stream_generator_equivalence():
@@ -163,468 +78,5 @@ def test_disordered_event_stream_generator_equivalence():
     res = eng.stream(ats, axs)
     ref_ts, ref_ys = in_order_reference(m, ats, axs, 20.0)
     assert np.array_equal(res.ts, ref_ts)
-    _assert_tree_close(res.ys, ref_ys, exact=True)
+    assert_tree_close(res.ys, ref_ys, exact=True)
     assert res.n_late == 0
-
-
-def test_property_disorder_equivalence_hypothesis():
-    """Hypothesis: ANY ts/value sequence with disorder ≤ slack reproduces
-    the sorted in-order reference bit-exactly (non-commutative affine_i32)."""
-    pytest.importorskip("hypothesis")
-    from hypothesis import given, settings, strategies as st
-
-    m = monoids.affine_int_monoid()
-
-    @settings(max_examples=25, deadline=None)
-    @given(data=st.data())
-    def inner(data):
-        T = data.draw(st.integers(2, 28))
-        gaps = data.draw(
-            st.lists(st.integers(0, 7), min_size=T, max_size=T)
-        )
-        ts = np.cumsum(np.asarray(gaps, np.int64)).astype(np.int32)
-        slack = data.draw(st.integers(0, 10))
-        delays = data.draw(
-            st.lists(st.integers(0, max(slack, 0)), min_size=T, max_size=T)
-        )
-        order = np.argsort(ts + np.asarray(delays, np.int32), kind="stable")
-        horizon = data.draw(st.integers(1, 12))
-        a = np.asarray(
-            data.draw(st.lists(st.integers(-4, 4), min_size=T, max_size=T)),
-            np.int32,
-        )
-        b = np.asarray(
-            data.draw(st.lists(st.integers(-4, 4), min_size=T, max_size=T)),
-            np.int32,
-        )
-        xs = (jnp.asarray(a[:, None]), jnp.asarray(b[:, None]))
-        axs = jax.tree.map(lambda v: v[order], xs)
-        ats = ts[order]
-        eng = EventTimeChunkedStream(
-            m, horizon, slack=slack, chunk=8, capacity=T + 2, buffer=T + 2,
-            ts_dtype=jnp.int32,
-        )
-        res = eng.stream(jnp.asarray(ats), axs)
-        assert res.n_late == 0
-        ref_ts, ref_ys = in_order_reference(m, ats, axs, horizon)
-        assert np.array_equal(res.ts, ref_ts)
-        _assert_tree_close(res.ys, ref_ys, exact=True)
-
-    inner()
-
-
-# ---------------------------------------------------------------------------
-# Watermark edge cases
-# ---------------------------------------------------------------------------
-
-
-def test_gap_empties_window_completely():
-    """A silence longer than the horizon evicts everything (empty window)."""
-    m = monoids.sum_monoid(jnp.int32)
-    ts = np.asarray([0, 1, 2, 50, 51, 200], np.float32)
-    xs = jnp.asarray(np.arange(6, dtype=np.int32).reshape(6, 1) + 1)
-    eng = EventTimeChunkedStream(m, 5.0, slack=0.0, chunk=4, capacity=8, buffer=4)
-    res = eng.stream(jnp.asarray(ts), xs)
-    assert np.asarray(res.ys)[:, 0].tolist() == [1, 3, 6, 4, 9, 6]
-    # the terminal flush watermark (+inf) evicts the whole window...
-    assert int(eng.window_fold(res.state)[0]) == 0
-    # ...while an unflushed stream keeps the live tail
-    live = eng.stream(jnp.asarray(ts), xs, flush=False)
-    assert int(eng.window_fold(live.state)[0]) == 6
-
-
-def test_empty_and_single_element_streams():
-    m = monoids.sum_monoid(jnp.int32)
-    eng = EventTimeChunkedStream(m, 5.0, chunk=4, capacity=8, buffer=4)
-    res = eng.stream(jnp.zeros((0,), jnp.float32), jnp.zeros((0, 1), jnp.int32))
-    assert res.ts.shape == (0,) and res.ys is None
-    res = eng.stream(jnp.asarray([3.0]), jnp.asarray([[7]], jnp.int32))
-    assert np.asarray(res.ys).ravel().tolist() == [7]
-
-
-def test_empty_chunk_with_pending_buffer_refuses_silent_skip():
-    """flush=True on an empty chunk cannot drain a pending buffer — the
-    engine must say so instead of quietly dropping the pending outputs."""
-    m = monoids.sum_monoid(jnp.int32)
-    eng = EventTimeChunkedStream(m, 5.0, slack=3.0, chunk=4, capacity=8, buffer=4)
-    part = eng.stream(
-        jnp.asarray([0.0, 1.0, 2.0]), jnp.ones((3, 1), jnp.int32), flush=False
-    )
-    with pytest.raises(ValueError, match="pending"):
-        eng.stream(
-            jnp.zeros((0,), jnp.float32), jnp.zeros((0, 1), jnp.int32),
-            state=part.state,
-        )
-    # the documented path drains it
-    st, out = eng.flush(part.state, jnp.zeros((1, 1), jnp.int32))
-    assert int(out["mask"].sum()) > 0
-
-
-def test_all_late_chunk_policies():
-    """A chunk arriving entirely below the watermark: drop / side_output
-    discard it (flagged), merge folds it into the live window."""
-    m = monoids.sum_monoid(jnp.int32)
-    ts = np.asarray([10, 11, 12, 13, 1, 2, 3, 4], np.float32)
-    xs = jnp.ones((8, 1), jnp.int32)
-    for policy in ("drop", "side_output"):
-        eng = EventTimeChunkedStream(
-            m, 100.0, slack=0.0, chunk=4, capacity=16, buffer=4,
-            late_policy=policy,
-        )
-        res = eng.stream(jnp.asarray(ts), xs, flush=False)
-        assert res.n_late == 4 and res.n_dropped == 4
-        assert res.late_rows.tolist() == [4, 5, 6, 7]
-        assert np.asarray(res.ys).ravel().tolist() == [1, 2, 3, 4]
-        assert int(eng.window_fold(res.state)[0]) == 4
-    eng = EventTimeChunkedStream(
-        m, 100.0, slack=0.0, chunk=4, capacity=16, buffer=4, late_policy="merge"
-    )
-    res = eng.stream(jnp.asarray(ts), xs, flush=False)
-    assert res.n_late == 4 and res.n_dropped == 0
-    assert int(eng.window_fold(res.state)[0]) == 8  # merged into the window
-
-
-def test_merge_policy_drops_past_horizon_late_data():
-    """Merge policy still drops late data older than the live horizon."""
-    m = monoids.sum_monoid(jnp.int32)
-    ts = np.asarray([100, 101, 102, 103, 1, 99, 102.5, 60], np.float32)
-    xs = jnp.ones((8, 1), jnp.int32)
-    eng = EventTimeChunkedStream(
-        m, 10.0, slack=0.0, chunk=4, capacity=16, buffer=4, late_policy="merge"
-    )
-    res = eng.stream(jnp.asarray(ts), xs, flush=False)
-    # ts=1 and ts=60 are beyond horizon -> dropped; 99, 102.5 merge
-    assert res.n_dropped == 2
-    assert int(eng.window_fold(res.state)[0]) == 6
-
-
-def test_buffer_overflow_raises():
-    m = monoids.sum_monoid(jnp.int32)
-    eng = EventTimeChunkedStream(m, 10.0, slack=1000.0, chunk=4, capacity=8, buffer=2)
-    with pytest.raises(RuntimeError, match="overflow"):
-        eng.stream(
-            jnp.asarray(np.arange(12, dtype=np.float32)),
-            jnp.ones((12, 1), jnp.int32),
-        )
-
-
-def test_window_capacity_overflow_raises():
-    m = monoids.sum_monoid(jnp.int32)
-    eng = EventTimeChunkedStream(m, 1000.0, slack=0.0, chunk=8, capacity=4, buffer=8)
-    with pytest.raises(RuntimeError, match="overflow"):
-        eng.stream(
-            jnp.asarray(np.arange(16, dtype=np.float32)),
-            jnp.ones((16, 1), jnp.int32),
-        )
-
-
-# ---------------------------------------------------------------------------
-# Per-element protocol + primitives
-# ---------------------------------------------------------------------------
-
-
-def test_timestamped_window_matches_reference():
-    m, mk, _ = MONOID_CASES["affine_i32"]
-    T = 40
-    ts = np.sort(rng.uniform(0, 80, T)).astype(np.float32)
-    xs = mk((T, 1))
-    ref_ts, ref_ys = in_order_reference(m, ts, xs, 13.0)
-    win = TimestampedWindow(daba_lite, m, horizon=13.0, capacity=64)
-    for i in range(T):
-        win.insert(float(ts[i]), jax.tree.map(lambda a: a[i, 0], xs))
-        _assert_tree_close(
-            win.query(), jax.tree.map(lambda a: a[i, 0], ref_ys), exact=True, ctx=i
-        )
-
-
-def test_timestamped_window_watermark_bulk_evict_and_order_check():
-    m = monoids.sum_monoid(jnp.int32)
-    win = TimestampedWindow(daba_lite, m, horizon=5.0, capacity=32)
-    for t in range(8):
-        win.insert(float(t), 1)
-    assert win.size() == 5  # (7-5, 7] keeps ts 3..7
-    evicted = win.advance(100.0)  # watermark jump: ONE bulk evict of the rest
-    assert evicted == 5 and win.size() == 0
-    assert int(m.lower(win.query())) == 0
-    with pytest.raises(ValueError, match="event-time order"):
-        win.insert(50.0, 1)  # below the 100.0 watermark path max
-
-
-def test_range_fold_matches_naive():
-    m = monoids.affine_int_monoid()
-    M, Q = 23, 17
-    arr = jax.vmap(m.lift)(
-        (jnp.asarray(rng.integers(-4, 4, M), jnp.int32),
-         jnp.asarray(rng.integers(-4, 4, M), jnp.int32))
-    )
-    starts = jnp.asarray(rng.integers(0, M, Q), jnp.int32)
-    ends = jnp.asarray(
-        np.minimum(np.asarray(starts) + rng.integers(-1, 9, Q), M - 1), jnp.int32
-    )
-    got = range_fold(m, arr, starts, ends)
-    for q in range(Q):
-        acc = m.identity()
-        for i in range(int(starts[q]), int(ends[q]) + 1):
-            acc = m.combine(acc, swag_base.tree_index(arr, i))
-        _assert_tree_close(swag_base.tree_index(got, q), acc, exact=True, ctx=q)
-
-
-def _flip_queries(M, layout, r):
-    """Monotone query sets satisfying the flip invariant: ``ends`` strictly
-    increasing, ``starts`` non-decreasing (module docstring)."""
-    if layout == "singleton":  # every element its own single-entry window
-        ends = np.arange(M, dtype=np.int32)
-        return ends.copy(), ends
-    if layout == "giant":  # one giant segment: every query starts at 0
-        ends = np.sort(r.choice(M, size=min(M, 13), replace=False))
-        return np.zeros_like(ends, np.int32), ends.astype(np.int32)
-    if layout == "empty":  # every span empty → identity rows
-        ends = np.sort(r.choice(M, size=min(M, 11), replace=False))
-        return (ends + 1).astype(np.int32), ends.astype(np.int32)
-    # random widths; max-accumulate keeps starts monotone (and ≤ ends,
-    # since each ends[q'] - w[q'] ≤ ends[q'] ≤ ends[q])
-    ends = np.sort(r.choice(M, size=min(M, 17), replace=False))
-    starts = np.maximum.accumulate(ends - r.integers(0, M, ends.shape[0]))
-    return np.clip(starts, 0, None).astype(np.int32), ends.astype(np.int32)
-
-
-@pytest.mark.parametrize("mname", ["affine_i32", "m4_int", "argmax"])
-@pytest.mark.parametrize("layout", ["random", "giant", "singleton", "empty"])
-def test_flip_range_fold_matches_retired_table_and_naive(mname, layout):
-    """The constant-combine flip sweep ≡ the retired doubling table ≡ the
-    per-element loop, bit-exactly, on flip-invariant query sets — including
-    non-commutative monoids, a single giant segment, every-element-its-own-
-    window, and empty spans."""
-    m, mk, _ = MONOID_CASES[mname]
-    r = np.random.default_rng(sum(map(ord, mname + layout)))
-    M = 29
-    arr = jax.vmap(m.lift)(mk((M,)))
-    starts, ends = _flip_queries(M, layout, r)
-    got = flip_range_fold(m, arr, starts, ends)
-    table = range_fold(m, arr, starts, ends)
-    _assert_tree_close(got, table, exact=True, ctx=(mname, layout))
-    for q in range(len(ends)):
-        acc = m.identity()
-        for i in range(int(starts[q]), int(ends[q]) + 1):
-            acc = m.combine(acc, swag_base.tree_index(arr, i))
-        _assert_tree_close(
-            swag_base.tree_index(got, q), acc, exact=True,
-            ctx=(mname, layout, q),
-        )
-
-
-def test_engine_gap_restart_and_giant_window_bit_exact():
-    """Flip-sweep edge cases at engine level, non-commutative monoid:
-    a horizon covering the whole stream (single giant segment — every
-    released window starts at merge position 0) and a mid-stream time gap
-    far beyond the horizon (bulk-evicts the ENTIRE window, restarting from
-    empty), both bit-exact vs the in-order reference."""
-    m, mk, _ = MONOID_CASES["affine_i32"]
-    T, B = 48, 2
-    ts = np.sort(rng.uniform(0, 20.0, T)).astype(np.float32)
-    ts[T // 2:] += 500.0  # gap ≫ any horizon below: empty-window restart
-    xs = mk((T, B))
-    for horizon in (1e6, 7.0):  # giant window; ordinary window across the gap
-        eng = EventTimeChunkedStream(
-            m, horizon, slack=0.0, chunk=16, capacity=128, buffer=16
-        )
-        res = eng.stream(jnp.asarray(ts), xs)
-        ref_ts, ref_ys = in_order_reference(m, ts, xs, horizon)
-        assert np.array_equal(res.ts, ref_ts)
-        _assert_tree_close(res.ys, ref_ys, exact=True, ctx=horizon)
-
-
-def test_engine_all_late_chunk_bit_exact():
-    """A chunk arriving entirely below the watermark (all-late) is dropped
-    and counted without disturbing on-time outputs."""
-    m, mk, _ = MONOID_CASES["affine_i32"]
-    T, B, C = 32, 1, 8
-    ts = np.sort(rng.uniform(0, 60.0, T)).astype(np.float32)
-    xs = mk((T, B))
-    # splice one whole chunk of ancient events into the middle of the stream
-    late_ts = np.full(C, -100.0, np.float32)
-    ats = np.concatenate([ts[:16], late_ts, ts[16:]])
-    axs = jax.tree.map(
-        lambda a: jnp.concatenate([a[:16], jnp.zeros((C,) + a.shape[1:],
-                                                     a.dtype), a[16:]]), xs
-    )
-    eng = EventTimeChunkedStream(m, 9.0, slack=0.0, chunk=C, capacity=64,
-                                 buffer=16)
-    res = eng.stream(jnp.asarray(ats), axs)
-    assert int(res.n_late) == C and int(res.n_dropped) == C
-    ref_ts, ref_ys = in_order_reference(m, ts, xs, 9.0)
-    assert np.array_equal(res.ts, ref_ts)
-    _assert_tree_close(res.ys, ref_ys, exact=True)
-
-
-def test_eventtime_combines_per_position_flat_in_horizon():
-    """The constant-combine claim, measured at runtime: ⊗-invocations per
-    swept merge position stay flat as the horizon (and window capacity)
-    grow — the retired doubling table grew as log2(W+C)."""
-    T, B, chunk, buffer = 512, 1, 64, 32
-    ts = np.sort(rng.uniform(0, float(T), T)).astype(np.float32)
-    xs = jnp.asarray(rng.standard_normal((T, B)), jnp.float32)
-    per_pos = {}
-    for horizon in (8.0, 64.0, 512.0):
-        cap = 2 * int(horizon) + 32
-        eng = EventTimeChunkedStream(
-            monoids.max_monoid(), horizon, slack=0.0, chunk=chunk,
-            capacity=cap, buffer=buffer, instrument_combines=True,
-        )
-        obs_counters.combines.reset()
-        eng.stream(jnp.asarray(ts), xs)
-        # each chunk sweeps M = capacity + buffer + chunk merge positions;
-        # the chunk count is identical across horizons, so it cancels
-        # (read() runs effects_barrier before snapshotting)
-        per_pos[horizon] = (
-            obs_counters.combines.read()["eventtime"] / (cap + buffer + chunk)
-        )
-    lo, hi = min(per_pos.values()), max(per_pos.values())
-    assert lo > 0, per_pos  # the instrumentation actually fired
-    assert hi <= 1.5 * lo, per_pos
-    # absolute guard: the flip sweep measures ~38 here (9 chunk sweeps of
-    # ~4.3 ⊗/position); re-adding a doubling table would roughly triple it
-    assert hi <= 60, per_pos
-
-
-def test_range_fold_invertible_matches_generic():
-    m = monoids.sum_monoid(jnp.int32)
-    M, Q = 19, 11
-    arr = jax.vmap(m.lift)(jnp.asarray(rng.integers(-9, 9, M), jnp.int32))
-    starts = jnp.asarray(rng.integers(0, M, Q), jnp.int32)
-    ends = jnp.asarray(
-        np.minimum(np.asarray(starts) + rng.integers(-1, 7, Q), M - 1), jnp.int32
-    )
-    a = range_fold(m, arr, starts, ends)
-    b = range_fold_invertible(m, arr, starts, ends)
-    _assert_tree_close(a, b, exact=True)
-
-
-def test_fold_axis0_ordered():
-    m = monoids.affine_int_monoid()
-    vals = (jnp.asarray(rng.integers(-4, 4, 9), jnp.int32),
-            jnp.asarray(rng.integers(-4, 4, 9), jnp.int32))
-    lifted = jax.vmap(m.lift)(vals)
-    acc = m.identity()
-    for i in range(9):
-        acc = m.combine(acc, swag_base.tree_index(lifted, i))
-    _assert_tree_close(fold_axis0(m, lifted), acc, exact=True)
-
-
-def test_chunked_stream_timestamped_factory():
-    eng = ChunkedStream.timestamped(monoids.sum_monoid(), 5.0, chunk=8)
-    assert isinstance(eng, EventTimeChunkedStream)
-    res = eng.stream(
-        jnp.asarray([0.0, 1.0, 2.0]), jnp.ones((3, 1), jnp.float32)
-    )
-    assert np.asarray(res.ys).ravel().tolist() == [1.0, 2.0, 3.0]
-
-
-def test_stream_continuation_across_calls():
-    """stream(state=...) continues a live event-time window."""
-    m = monoids.sum_monoid(jnp.int32)
-    ts = np.sort(rng.uniform(0, 50, 40)).astype(np.float32)
-    xs = _scalar_vals((40, 1), jnp.int32)
-    eng = EventTimeChunkedStream(m, 9.0, slack=0.0, chunk=8, capacity=32, buffer=8)
-    full = eng.stream(jnp.asarray(ts), xs)
-    st = eng.init_state(1)
-    first = eng.stream(jnp.asarray(ts[:25]), xs[:25], state=st, flush=False)
-    second = eng.stream(jnp.asarray(ts[25:]), xs[25:], state=first.state)
-    got = np.concatenate([np.asarray(first.ys), np.asarray(second.ys)])
-    assert np.array_equal(got, np.asarray(full.ys))
-
-
-# ---------------------------------------------------------------------------
-# Disorder-adaptive release path
-# ---------------------------------------------------------------------------
-
-
-def _adversarial_max_late(T, slack, *, seed):
-    """Every other row delayed by EXACTLY ``slack``: each chunk mixes
-    frontier rows with maximally-late ones, so no chunk is in-order and the
-    bounded merge runs at its admissible-distance ceiling.  (An all-equal
-    delay would leave the stream in-order — the alternation is the point.)"""
-    r = np.random.default_rng(seed)
-    ts = np.sort(r.integers(0, 3 * T, T)).astype(np.float32)
-    delay = np.float32(slack) * (np.arange(T) % 2).astype(np.float32)
-    order = np.argsort(ts + delay, kind="stable")
-    return ts[order], order
-
-
-@pytest.mark.parametrize("mname", ["affine_i32", "m4_int", "argmax"])
-@pytest.mark.parametrize("disorder", [0.0, 0.1, 0.5, "max_late"])
-def test_adaptive_release_path_bit_exact(mname, disorder):
-    """The disorder-adaptive release path (no-sort compact merge at d = 0,
-    bounded merge above) is invisible in the outputs: bit-exact vs the
-    in-order reference for NON-commutative monoids across disorder levels —
-    including the adversarial alternating maximally-late stream — with a
-    ragged final chunk (T % chunk != 0)."""
-    m, mk, _ = MONOID_CASES[mname]
-    T, B, slack = 75, 2, 9.0
-    seed = sum(map(ord, mname)) + (97 if disorder == "max_late"
-                                   else int(disorder * 10))
-    if disorder == "max_late":
-        ats, order = _adversarial_max_late(T, slack, seed=seed)
-    else:
-        ats, order = _disordered(T, disorder, slack, seed=seed)
-    xs = mk((T, B))
-    axs = jax.tree.map(lambda a: a[order], xs)
-    horizon = 21.0
-    eng = EventTimeChunkedStream(m, horizon, slack=slack, chunk=16,
-                                 capacity=160, buffer=64)
-    res = eng.stream(jnp.asarray(ats), axs)
-    assert res.n_late == 0 and res.n_dropped == 0
-    ref_ts, ref_ys = in_order_reference(m, ats, axs, horizon)
-    assert np.array_equal(res.ts, ref_ts)
-    _assert_tree_close(res.ys, ref_ys, exact=True, ctx=(mname, disorder))
-
-
-def test_release_branch_counters_zero_sorts_in_order():
-    """Fast-path regression guard: an in-order stream must dispatch ZERO
-    sorting (slow) release branches — every chunk, including the flush
-    drain, rides the no-sort compact merge — while a disordered stream must
-    take the slow branch at least once.  Branch taken is counted per chunk
-    in ``obs.counters.releases`` when ``instrument_release=True``."""
-    m = monoids.sum_monoid(jnp.int32)
-    T, B = 96, 1
-    ts = np.sort(rng.uniform(0, 200.0, T)).astype(np.float32)
-    xs = _scalar_vals((T, B), jnp.int32)
-    eng = EventTimeChunkedStream(m, 20.0, slack=4.0, chunk=16, capacity=96,
-                                 buffer=32, instrument_release=True)
-    obs_counters.releases.reset()
-    res = eng.stream(jnp.asarray(ts), xs)
-    counts = obs_counters.releases.read()  # read() barriers the callbacks
-    assert counts["slow"] == 0
-    assert counts["fast"] >= T // 16  # every full chunk counted
-    ref_ts, ref_ys = in_order_reference(m, ts, xs, 20.0)
-    assert np.array_equal(res.ts, ref_ts)
-    _assert_tree_close(res.ys, ref_ys, exact=True)
-
-    ats, order = _disordered(T, 0.5, 4.0, seed=3)
-    axs = jax.tree.map(lambda a: a[order], xs)
-    obs_counters.releases.reset()
-    eng.stream(jnp.asarray(ats), axs)
-    counts = obs_counters.releases.read()
-    assert counts["slow"] > 0
-
-
-def test_ooo_distance_gauges_track_measured_disorder():
-    """``obs_metrics`` exposes the measured out-of-order distance of recent
-    chunks: zero across an in-order stream, positive once a disordered one
-    has been processed (the slow branch records the exact displacement)."""
-    m = monoids.sum_monoid(jnp.int32)
-    T = 64
-    ts = np.sort(rng.uniform(0, 100.0, T)).astype(np.float32)
-    xs = _scalar_vals((T, 1), jnp.int32)
-    eng = EventTimeChunkedStream(m, 20.0, slack=8.0, chunk=16, capacity=96,
-                                 buffer=64)
-    res = eng.stream(jnp.asarray(ts), xs)
-    metrics = eng.obs_metrics(res.state)
-    assert int(metrics["ooo_distance_max"]) == 0
-    assert float(metrics["ooo_distance_p95"]) == 0.0
-
-    ats, order = _disordered(T, 0.5, 8.0, seed=11)
-    axs = jax.tree.map(lambda a: a[order], xs)
-    res = eng.stream(jnp.asarray(ats), axs)
-    metrics = eng.obs_metrics(res.state)
-    assert int(metrics["ooo_distance_max"]) > 0

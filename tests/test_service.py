@@ -16,6 +16,7 @@ The contract under test:
     exposition and agree with the service's own counters.
 """
 
+import contextlib
 import json
 import threading
 import urllib.error
@@ -38,10 +39,26 @@ from repro.service import (
 
 rng = np.random.default_rng(7)
 
+# drain waits: a stalled consumer fails its test here, inside the per-test
+# limit (conftest.TEST_LIMIT_S: a flush and a stop fit in it), and not
+# after the service's 300-s defaults; the first flush, which compiles the
+# drain programs, takes ~35 s on a loaded CPU host
+DRAIN_S = 180.0
+
 CFG = dict(window=32, horizon=4.0, slots=128, chunk=128, max_batch=64,
            quota_rows_per_s=1e9, quota_burst=1e9, rollup=True,
            rollup_window=8, kll_k=16, kll_levels=4, hll_registers=16,
            topk_k=4, latency_ring=1024)
+
+
+@contextlib.contextmanager
+def _running(svc):
+    """Start ``svc``; on exit stop it, draining within ``DRAIN_S``."""
+    svc.start()
+    try:
+        yield svc
+    finally:
+        svc.stop(timeout=DRAIN_S)
 
 
 def _batches(n_batches, n=48, keys_hi=20, seed=0, t0=0.0):
@@ -131,13 +148,13 @@ def test_service_folds_match_offline_replay():
     """The tentpole bit-exactness claim, two interleaved tenants."""
     cfg = ServiceConfig(**CFG)
     qk = list(range(20))
-    with AnalyticsService(cfg) as svc:
+    with _running(AnalyticsService(cfg)) as svc:
         ba = _batches(6, seed=1)
         bb = _batches(6, seed=2)
         for (ka, ta, xa), (kb, tb, xb) in zip(ba, bb):
             assert svc.ingest("a", ka, ta, xa)[0] == 200
             assert svc.ingest("b", kb, tb, xb)[0] == 200
-        assert svc.flush()
+        assert svc.flush(timeout=DRAIN_S)
         for name, batches in (("a", ba), ("b", bb)):
             code, snap = svc.query(name, keys=qk)
             assert code == 200
@@ -155,7 +172,7 @@ def test_quota_throttles_one_tenant_not_the_other():
     cfg = ServiceConfig(**{**CFG, "quota_rows_per_s": 1.0,
                            "quota_burst": 150.0})
     qk = list(range(20))
-    with AnalyticsService(cfg) as svc:
+    with _running(AnalyticsService(cfg)) as svc:
         good = _batches(3, n=48, seed=3)
         noisy = _batches(5, n=48, seed=4)
         codes = []
@@ -164,7 +181,7 @@ def test_quota_throttles_one_tenant_not_the_other():
             if i < len(good):
                 assert svc.ingest("good", *good[i])[0] == 200
         assert codes.count(200) == 3 and codes.count(429) == 2
-        assert svc.flush()
+        assert svc.flush(timeout=DRAIN_S)
         _, snap_n = svc.query("noisy")
         assert snap_n["counters"]["throttled_rows"] == 2 * 48
         assert snap_n["counters"]["throttled_batches"] == 2
@@ -182,7 +199,7 @@ def test_quota_throttles_one_tenant_not_the_other():
 def test_retry_after_header_and_recovery():
     cfg = ServiceConfig(**{**CFG, "quota_rows_per_s": 1000.0,
                            "quota_burst": 10.0})
-    with AnalyticsService(cfg) as svc:
+    with _running(AnalyticsService(cfg)) as svc:
         k, t, x = np.asarray([1] * 10), np.linspace(0, 1, 10), np.ones(10)
         assert svc.ingest("a", k, t, x)[0] == 200
         code, payload, hdrs = svc.ingest("a", k, t + 2, x)
@@ -224,7 +241,7 @@ def test_stop_raises_when_drain_times_out():
 
 def test_malformed_and_unknown():
     cfg = ServiceConfig(**CFG)
-    with AnalyticsService(cfg) as svc:
+    with _running(AnalyticsService(cfg)) as svc:
         code, payload, _ = svc.ingest("a", [1, 2], [0.0], [1])
         assert code == 400
         code, _, _ = svc.ingest("a", [1], [1.0], [1])
@@ -232,13 +249,13 @@ def test_malformed_and_unknown():
         code, payload, _ = svc.ingest("a", [1], [0.5], [1])  # behind watermark
         assert code == 400
         assert svc.query("nope")[0] == 404
-        svc.flush()
+        svc.flush(timeout=DRAIN_S)
         assert svc.query("a", keys=[1 << 25])[0] == 400  # out of key space
 
 
 def test_tenant_capacity():
     cfg = ServiceConfig(**{**CFG, "max_tenants": 2})
-    with AnalyticsService(cfg) as svc:
+    with _running(AnalyticsService(cfg)) as svc:
         assert svc.ingest("a", [1], [0.0], [1])[0] == 200
         assert svc.ingest("b", [1], [0.0], [1])[0] == 200
         assert svc.ingest("c", [1], [0.0], [1])[0] == 503
@@ -246,7 +263,7 @@ def test_tenant_capacity():
 
 def test_rollup_sketches_in_query():
     cfg = ServiceConfig(**CFG)
-    with AnalyticsService(cfg) as svc:
+    with _running(AnalyticsService(cfg)) as svc:
         r = np.random.default_rng(0)
         # heavy key 3: half of all rows
         keys = np.where(r.random(64 * 4) < 0.5, 3, r.integers(0, 16, 64 * 4))
@@ -255,7 +272,7 @@ def test_rollup_sketches_in_query():
         for i in range(4):
             sl = slice(64 * i, 64 * (i + 1))
             assert svc.ingest("a", keys[sl], ts[sl], xs[sl])[0] == 200
-        assert svc.flush()
+        assert svc.flush(timeout=DRAIN_S)
         _, snap = svc.query("a", top=3)
         assert snap["hot_keys"][0][0] == 3           # heavy hitter surfaced
         assert snap["value_quantiles"]["p50"] == 7.0  # constant values
@@ -267,10 +284,10 @@ def test_rollup_sketches_in_query():
 
 def test_stats_latency_percentiles():
     cfg = ServiceConfig(**CFG)
-    with AnalyticsService(cfg) as svc:
+    with _running(AnalyticsService(cfg)) as svc:
         for b in _batches(3, seed=8):
             assert svc.ingest("a", *b)[0] == 200
-        assert svc.flush()
+        assert svc.flush(timeout=DRAIN_S)
         s = svc.stats()
         assert s["drained_rows"] == 3 * 48
         lat = s["ingest_to_queryable"]
@@ -316,7 +333,7 @@ def test_http_end_to_end():
                 "values": x.tolist(),
             })
             assert code == 200 and payload["accepted"] == 48
-        assert svc.flush()
+        assert svc.flush(timeout=DRAIN_S)
         code, body = _get(f"{srv.url}/query?tenant=web&keys=0,1,2&top=4")
         assert code == 200
         snap = json.loads(body)
@@ -347,7 +364,7 @@ def test_http_429_surfaces_retry_after():
     cfg = ServiceConfig(**{**CFG, "quota_rows_per_s": 1.0,
                            "quota_burst": 20.0})
     svc = AnalyticsService(cfg)
-    with ServiceHTTPServer(svc) as srv:
+    with _running(svc), ServiceHTTPServer(svc) as srv:
         doc = {"tenant": "t", "keys": [1] * 16,
                "ts": list(np.linspace(0, 1, 16)), "values": [1] * 16}
         assert _post(f"{srv.url}/ingest", doc)[0] == 200
@@ -360,7 +377,7 @@ def test_http_429_surfaces_retry_after():
 def test_http_concurrent_ingest_two_tenants():
     """Parallel handler threads → consistent accounting, no lost rows."""
     cfg = ServiceConfig(**CFG)
-    with AnalyticsService(cfg) as svc, ServiceHTTPServer(svc) as srv:
+    with _running(AnalyticsService(cfg)) as svc, ServiceHTTPServer(svc) as srv:
         errs = []
 
         def pump(tenant, seed):
@@ -381,7 +398,7 @@ def test_http_concurrent_ingest_two_tenants():
         for th in threads:
             th.join()
         assert not errs
-        assert svc.flush()
+        assert svc.flush(timeout=DRAIN_S)
         stats = svc.stats()
         assert stats["drained_rows"] == 3 * 6 * 32
         for i in range(3):

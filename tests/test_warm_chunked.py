@@ -1,61 +1,20 @@
-"""Warm-state carry protocol: chunked-from-warm ≡ per-element scan.
+"""Warm-state carry protocol: each algorithm's state ≡ its carry.
 
-Covers the PR-2 tentpole:
+Covers:
   * ``state_to_carry`` specializations vs the generic evict/query oracle,
     for every algorithm × int/float/pytree/non-commutative monoids;
   * carry → state → carry round trips (exact for integer monoids) and live
-    continuation of reconstructed states;
-  * ``state_from_chunk`` (the vectorized final-state rebuild) vs bulk insert;
-  * ``BatchedSWAG.stream`` warm routing: chunked ≡ per-element from live
-    (and ragged per-lane) windows, across ragged chunk splits, both the
-    Pallas-kernel path (scalar ops) and the generic pytree path;
-  * the ragged-last-chunk identity padding reuses one compilation.
+    continuation of reconstructed states, FlatFIT included;
+  * ``state_from_chunk`` (the vectorized final-state rebuild) vs bulk insert.
+
+``BatchedSWAG.stream``'s warm routing is test_warm_stream.py.
 """
 
-import jax
-import jax.numpy as jnp
-import numpy as np
 import pytest
 
-from repro.core import ALGORITHMS, GENERAL_ALGORITHMS, monoids, swag_base
-from repro.core.batched import BatchedSWAG
-from repro.core.chunked import ChunkedStream
+from repro.core import ALGORITHMS, swag_base
 
-rng = np.random.default_rng(1)
-
-
-def _scalar_vals(shape, dtype=jnp.float32):
-    if jnp.issubdtype(dtype, jnp.integer):
-        return jnp.asarray(rng.integers(-9, 9, shape), dtype)
-    return jnp.asarray(rng.standard_normal(shape), dtype)
-
-
-def _affine_vals(shape, dtype=jnp.int32):
-    return (
-        jnp.asarray(rng.integers(-5, 5, shape), dtype),
-        jnp.asarray(rng.integers(-5, 5, shape), dtype),
-    )
-
-
-# Spans the algebraic classes: commutative+invertible scalar (kernel path,
-# exact int), commutative invertible pytree, and two NON-commutative
-# NON-invertible monoids (one exact-integer, one float).
-MONOID_CASES = {
-    "sum_i32": (monoids.sum_monoid(jnp.int32),
-                lambda s: _scalar_vals(s, jnp.int32), True),
-    "mean": (monoids.mean_monoid(), _scalar_vals, False),
-    "affine_i32": (monoids.affine_int_monoid(), _affine_vals, True),
-    "m4": (monoids.m4_monoid(), _scalar_vals, False),
-}
-
-
-def _assert_tree_close(a, b, exact, ctx=""):
-    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-        x, y = np.asarray(x), np.asarray(y)
-        if exact:
-            assert np.array_equal(x, y), (ctx, x, y)
-        else:
-            assert np.allclose(x, y, rtol=1e-4, atol=1e-4), (ctx, x, y)
+from warm_cases import MONOID_CASES, assert_tree_close
 
 
 def _warm_single(algo, m, mk, n_ops, window, cap=64):
@@ -85,7 +44,7 @@ def test_state_to_carry_matches_generic_oracle(algo_name, mname):
         st, _ = _warm_single(algo, m, mk, n_ops, window)
         carry_s = algo.state_to_carry(m, st, window)
         carry_g = swag_base.generic_state_to_carry(algo, m, st, window)
-        _assert_tree_close(carry_s, carry_g, exact, (algo_name, mname, n_ops, window))
+        assert_tree_close(carry_s, carry_g, exact, (algo_name, mname, n_ops, window))
 
 
 # ---------------------------------------------------------------------------
@@ -110,7 +69,7 @@ def test_carry_round_trip_and_continuation(algo_name, mname):
     st2 = swag_base.carry_to_state(algo, m, carry, 64)
     # carry -> state -> carry is exact (same suffix folds)
     carry2 = swag_base.state_to_carry(algo, m, st2, window)
-    _assert_tree_close(carry, carry2, exact, (algo_name, mname, "roundtrip"))
+    assert_tree_close(carry, carry2, exact, (algo_name, mname, "roundtrip"))
     # the reconstructed state keeps behaving like a per-element state seeded
     # with the same last window-1 elements the carry represents
     h = window - 1
@@ -119,7 +78,7 @@ def test_carry_round_trip_and_continuation(algo_name, mname):
         ref = algo.insert(m, ref, swag_base.tree_index(vals, i))
     assert int(algo.size(st2)) == int(algo.size(ref)) == h
     for step in range(h - 1):
-        _assert_tree_close(
+        assert_tree_close(
             m.lower(algo.query(m, st2)), m.lower(algo.query(m, ref)),
             exact, (algo_name, mname, "evict", step),
         )
@@ -128,7 +87,7 @@ def test_carry_round_trip_and_continuation(algo_name, mname):
     for i in range(4):
         v = swag_base.tree_index(more, i)
         st2, ref = algo.insert(m, st2, v), algo.insert(m, ref, v)
-        _assert_tree_close(
+        assert_tree_close(
             m.lower(algo.query(m, st2)), m.lower(algo.query(m, ref)),
             exact, (algo_name, mname, "insert", i),
         )
@@ -166,7 +125,7 @@ def test_flatfit_state_to_carry_matches_history_truth(mname):
         acc = m.identity()
         for j in hist[len(hist) - (h - t):]:
             acc = m.combine(acc, m.lift(swag_base.tree_index(vals, j)))
-        _assert_tree_close(
+        assert_tree_close(
             swag_base.tree_index(carry, t), acc, exact, (mname, t)
         )
 
@@ -184,7 +143,7 @@ def test_flatfit_carry_round_trip_and_continuation(mname):
     carry = swag_base.state_to_carry(ALGORITHMS["daba_lite"], m, st, window)
     ff = flatfit.carry_to_state(m, carry, 64)
     carry2 = flatfit.state_to_carry(m, ff, window)
-    _assert_tree_close(carry, carry2, exact, (mname, "roundtrip"))
+    assert_tree_close(carry, carry2, exact, (mname, "roundtrip"))
     h = window - 1
     ref = ALGORITHMS["daba_lite"].init(m, 64)
     for i in range(n_ops - h, n_ops):
@@ -197,7 +156,7 @@ def test_flatfit_carry_round_trip_and_continuation(mname):
         ref = ALGORITHMS["daba_lite"].insert(m, ref, v)
         ff = flatfit.evict(m, ff)
         ref = ALGORITHMS["daba_lite"].evict(m, ref)
-        _assert_tree_close(
+        assert_tree_close(
             m.lower(flatfit.query(m, ff)),
             m.lower(ALGORITHMS["daba_lite"].query(m, ref)),
             exact, (mname, "continue", i),
@@ -215,7 +174,7 @@ def test_flatfit_state_from_chunk_dispatcher():
     ref = flatfit.insert_bulk(m, flatfit.init(m, 32), vals)
     assert flatfit.size(st) == flatfit.size(ref) == 7
     for _ in range(7):
-        _assert_tree_close(
+        assert_tree_close(
             m.lower(flatfit.query(m, st)), m.lower(flatfit.query(m, ref)),
             exact, "state_from_chunk",
         )
@@ -240,148 +199,8 @@ def test_state_from_chunk_matches_bulk_insert(algo_name, mname):
         ref = swag_base.insert_bulk(algo, m, algo.init(m, 32), vals)
         assert int(algo.size(st)) == int(algo.size(ref)) == k
         for step in range(k):
-            _assert_tree_close(
+            assert_tree_close(
                 m.lower(algo.query(m, st)), m.lower(algo.query(m, ref)),
                 exact, (algo_name, mname, k, step),
             )
             st, ref = algo.evict(m, st), algo.evict(m, ref)
-
-
-# ---------------------------------------------------------------------------
-# BatchedSWAG.stream: warm routing ≡ per-element
-# ---------------------------------------------------------------------------
-
-
-def _warm_batched(algo, m, mk, B, window, n_warm, cap):
-    b = BatchedSWAG(algo, m, cap)
-    st = b.init(B)
-    if n_warm:
-        st, _ = b.stream(st, mk((n_warm, B)), window, chunked=False)
-    return b, st
-
-
-@pytest.mark.parametrize("mname", sorted(MONOID_CASES))
-@pytest.mark.parametrize("algo_name", sorted(GENERAL_ALGORITHMS))
-def test_warm_stream_chunked_matches_per_element(algo_name, mname):
-    m, mk, exact = MONOID_CASES[mname]
-    algo = GENERAL_ALGORITHMS[algo_name]
-    window, B = 8, 3
-    for n_warm, T, chunk in [(0, 37, 16), (3, 37, 16), (11, 41, 13), (8, 20, 4)]:
-        b, st = _warm_batched(algo, m, mk, B, window, n_warm, cap=12)
-        xs = mk((T, B))
-        st_pe, ys_pe = b.stream(st, xs, window, chunked=False)
-        st_ch, ys_ch = b.stream(st, xs, window, chunked=True, chunk=chunk)
-        ctx = (algo_name, mname, n_warm, T, chunk)
-        _assert_tree_close(ys_ch, ys_pe, exact, ctx)
-        _assert_tree_close(b.query(st_ch), b.query(st_pe), exact, ctx)
-        # the rebuilt final state keeps behaving
-        more = mk((B,))
-        st_pe, st_ch = b.insert(st_pe, more), b.insert(st_ch, more)
-        st_pe, st_ch = b.evict(st_pe), b.evict(st_ch)
-        _assert_tree_close(b.query(st_ch), b.query(st_pe), exact, ctx)
-
-
-def test_warm_stream_ragged_lanes():
-    """Per-lane warm sizes differ (masked fills) — carries are extracted and
-    front-truncated per lane."""
-    m = monoids.sum_monoid(jnp.int32)
-    b = BatchedSWAG(ALGORITHMS["daba_lite"], m, 12)
-    st = b.init(3)
-    for t in range(6):
-        do_ins = jnp.asarray([True, t < 2, t < 5])
-        st = b.step(st, _scalar_vals((3,), jnp.int32), do_ins, jnp.zeros(3, bool))
-    assert sorted(np.asarray(b.size(st)).tolist()) == [2, 5, 6]
-    xs = _scalar_vals((41, 3), jnp.int32)
-    st_pe, ys_pe = b.stream(st, xs, 8, chunked=False)
-    st_ch, ys_ch = b.stream(st, xs, 8, chunked=True, chunk=16)
-    _assert_tree_close(ys_ch, ys_pe, exact=True)
-    _assert_tree_close(b.query(st_ch), b.query(st_pe), exact=True)
-
-
-def test_auto_routing_includes_warm_states(monkeypatch):
-    """A warm concrete state with T ≥ the auto threshold takes the chunked
-    path (engine cache populated); oversized lanes fall back."""
-    from repro.core import batched as batched_mod
-
-    monkeypatch.setattr(batched_mod, "CHUNKED_AUTO_MIN_T", 32)
-    m = monoids.sum_monoid(jnp.int32)
-    b = BatchedSWAG(ALGORITHMS["daba_lite"], m, 12)
-    st = b.init(2)
-    st, _ = b.stream(st, _scalar_vals((10, 2), jnp.int32), 8, chunked=False)
-    assert not b._chunked_engines
-    xs = _scalar_vals((40, 2), jnp.int32)
-    st_ch, ys_ch = b.stream(st, xs, 8)
-    assert b._chunked_engines, "warm stream should auto-route through chunked"
-    _, ys_pe = b.stream(st, xs, 8, chunked=False)
-    _assert_tree_close(ys_ch, ys_pe, exact=True)
-
-
-def test_warm_auto_routing_at_real_threshold_exact():
-    """No monkeypatching: a warm stream at T ≥ CHUNKED_AUTO_MIN_T takes the
-    chunked engine and matches the per-element scan bit-exactly (int sum)."""
-    from repro.core.batched import CHUNKED_AUTO_MIN_T
-
-    m = monoids.sum_monoid(jnp.int32)
-    b = BatchedSWAG(ALGORITHMS["daba_lite"], m, 36)
-    st = b.init(2)
-    st, _ = b.stream(st, _scalar_vals((40, 2), jnp.int32), 32, chunked=False)
-    xs = _scalar_vals((CHUNKED_AUTO_MIN_T + 100, 2), jnp.int32)
-    st_auto, ys_auto = b.stream(st, xs, 32)  # auto: warm + long → chunked
-    assert b._chunked_engines
-    st_pe, ys_pe = b.stream(st, xs, 32, chunked=False)
-    _assert_tree_close(ys_auto, ys_pe, exact=True)
-    _assert_tree_close(b.query(st_auto), b.query(st_pe), exact=True)
-
-
-def test_warm_stream_inside_jit_stays_per_element():
-    """Traced states cannot take the host-side chunk loop — auto routing
-    must quietly stay on the scan path under jit."""
-    m = monoids.sum_monoid(jnp.int32)
-    b = BatchedSWAG(ALGORITHMS["daba_lite"], m, 12)
-    st = b.init(2)
-    xs = _scalar_vals((40, 2), jnp.int32)
-
-    @jax.jit
-    def run(st, xs):
-        return b.stream(st, xs, 8)[1]
-
-    _assert_tree_close(run(st, xs), b.stream(st, xs, 8, chunked=False)[1], True)
-
-
-# ---------------------------------------------------------------------------
-# ragged last chunk: identity padding, single compilation
-# ---------------------------------------------------------------------------
-
-
-def test_ragged_last_chunk_reuses_one_compilation():
-    m = monoids.sum_monoid(jnp.int32)
-    eng = ChunkedStream(m, window=8, chunk=16)
-    traces = []
-    orig = eng._process_chunk_impl
-
-    def counting_impl(carry, xs, mask=None):
-        traces.append(jax.tree.leaves(xs)[0].shape)
-        return orig(carry, xs, mask)
-
-    eng._jitted_pc = jax.jit(counting_impl)
-    xs = _scalar_vals((53, 2), jnp.int32)  # 3 full chunks + ragged 5
-    ys = eng.stream(xs)
-    assert len(traces) == 1, f"expected one trace, got shapes {traces}"
-    ref = ChunkedStream(m, window=8, chunk=53).stream(xs)
-    _assert_tree_close(ys, ref, exact=True)
-
-
-def test_masked_chunk_positions_are_identity():
-    """Masked positions act as monoid identity on both engine paths."""
-    for m, mk, exact in [MONOID_CASES["sum_i32"], MONOID_CASES["mean"]]:
-        eng = ChunkedStream(m, window=4, chunk=8)
-        xs = mk((8, 2))
-        mask = jnp.arange(8) < 5
-        carry = eng.init_carry(2)
-        _, y = eng.process_chunk(carry, xs, mask)
-        ref = ChunkedStream(m, window=4, chunk=5).stream(
-            jax.tree.map(lambda a: a[:5], xs)
-        )
-        _assert_tree_close(
-            jax.tree.map(lambda a: a[:5], y), ref, exact, m.name
-        )

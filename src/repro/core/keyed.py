@@ -148,6 +148,53 @@ def _take0(tree: PyTree, idx) -> PyTree:
     return jax.tree.map(lambda a: a[idx], tree)
 
 
+def _shift_lanes(tree: PyTree, shift, h: int) -> PyTree:
+    """Lane t of row k becomes lane ``min(t + shift_k, h - 1)`` of the same
+    row, for ``(C, h, ...)`` leaves: one static left shift by 2**i (last
+    lane repeated) per bit of ``min(shift, h - 1)``, taken by an
+    elementwise select where the row's bit is set.  Clamped shifts compose
+    (``min(min(t + a, h-1) + b, h-1) == min(t + a + b, h-1)``), so the
+    result equals the clipped-index lane gather bit for bit."""
+    s = jnp.clip(shift, 0, h - 1)
+    for i in range((h - 1).bit_length()):
+        d = 1 << i
+        take = (s & d) != 0
+
+        def step(x):
+            tail = jnp.broadcast_to(x[:, -1:], (x.shape[0], d) + x.shape[2:])
+            moved = jnp.concatenate([x[:, d:], tail], axis=1)
+            return jnp.where(_bc(take, x), moved, x)
+
+        tree = jax.tree.map(step, tree)
+    return tree
+
+
+def _row_windows(tree: PyTree, end, width: int) -> PyTree:
+    """Row k becomes rows ``end_k - width + 1 .. end_k`` of the ``(C, ...)``
+    leaves along a new axis 1, one contiguous run per row.  The ``(C,
+    width)`` Hankel view (row i = rows ``i - width + 1 .. i``) is built
+    from static row slices, doubling its width per pass, and then
+    row-gathered by ``end``.  Zero rows stand in for the positions before
+    row 0 (callers must mask those lanes).  (A ``dynamic_slice`` per row
+    compiles for a TPU v5e to a sequential loop over the rows.)"""
+
+    def take(x):
+        C = x.shape[0]
+        zeros = lambda n: jnp.zeros((n,) + x.shape[1:], x.dtype)
+        # right padding: the passes below drop up to 2 * width - 3 rows
+        hk = jnp.concatenate([zeros(width - 1), x, zeros(width)])[:, None]
+        k = 1  # hk[i, j] == padded row i + j, for j < k
+        while k < width:
+            n = hk.shape[0] - k
+            hk = jnp.concatenate(
+                [hk[:n], hk[k : k + n, : min(k, width - k)]], axis=1
+            )
+            k *= 2
+        return hk[:C][end]
+
+    return jax.tree.map(take, tree)
+
+
 # The segmented scans (seg_suffix_scan / seg_prefix_scan) live in
 # :mod:`repro.core.event_time` next to the flip-invariant statement they
 # implement; they are re-exported above for back-compat.
@@ -812,24 +859,20 @@ class KeyedWindowStore:
                 intra = _where_rows(starts >= cellstart, bpref, both)
 
         if h > 0:
-            # the ONE donated-buffer read: a contiguous (C, h) row gather;
-            # the refresh's shifted lanes t + n_seg and the warm-prefix lane
-            # min(p, h-1) are take_along_axis views of the gathered copy.
-            # (A single fused (C, h+1) 2-D lane gather straight off the
-            # donated buffer benchmarked ~15% slower — random (row, lane)
-            # addressing loses to contiguous row copies; two independent
-            # reads of the donated buffer break in-place donation outright.)
+            # the ONE donated-buffer read: a contiguous (C, h) row gather
+            # (two independent reads of the donated buffer break in-place
+            # donation).  Every (C, h) lane realignment after it is built
+            # from whole rows: the refresh's lanes min(t + n_seg, h-1) by
+            # log2(h) static shifts and selects, and the from-chunk lanes
+            # below by a row gather of a Hankel view.  On a TPU v5e an
+            # element-index gather over (C, h) costs ~11-20 ns per element
+            # (four of them took ~47 of a 63-ms step at C=4096, h=255),
+            # while row and slice gathers of the same bytes are cheap.  Only
+            # the (C, 1) warm-prefix lane pick stays a take_along_axis.
             # row_new rows' garbage is masked at every consumer (the
             # need_carry select below / the refresh's ``old_m`` mask).
-            t_ax = jnp.arange(h, dtype=jnp.int32)
-            old_t = jnp.clip(t_ax[None, :] + n_seg[:, None], 0, h - 1)
             crows = jax.tree.map(lambda cl: cl[cslot], state["carry"])
-            old = jax.tree.map(
-                lambda cr: jnp.take_along_axis(
-                    cr, old_t.reshape((C, h) + (1,) * (cr.ndim - 2)), axis=1
-                ),
-                crows,
-            )
+            old = _shift_lanes(crows, n_seg, h)
             pidx = jnp.clip(p, 0, h - 1)[:, None]
             if self.horizon is not None:
                 # the ONE carry_ts read (the ts mirror of ``crows``): t* =
@@ -870,11 +913,12 @@ class KeyedWindowStore:
         # Entry t of a head's refreshed carry folds the slot's trailing
         # h - t elements: a pure segment suffix when that fits in the chunk
         # (``from_chunk``), else surviving old-carry lane t + n_seg extended
-        # by the whole-segment fold.  (A "fused" two-gather variant with the
-        # whole-segment fold folded into the from_chunk gather via index
-        # clamping benchmarked ~2.3× SLOWER here: the broadcast ``whole``
-        # fuses into the select for free, a second data-dependent (C, h)
-        # gather does not.)
+        # by the whole-segment fold.  ``from_chunk`` on lane t reads the
+        # segment scan at row b - (h - t) + 1: for every row that is the
+        # contiguous run of rows b - hc + 1 .. b, one row of a Hankel view
+        # (:func:`_row_windows`), never a (C, hc) element-index gather.  The
+        # whole-segment fold is a (C,) pick broadcast over the lanes, which
+        # fuses into the select.
         if h > 0:
             ss = self._seg_scan(seg_end, lifted)
             old_m = _mask_tree(old, ~row_new, ident)
@@ -894,10 +938,12 @@ class KeyedWindowStore:
             # refresh at W=4096, C=1024).
             hc = min(h, C)
             h0 = h - hc
-            need = h - t_ax[h0:]  # (hc,) trailing elements entry t folds
+            # (hc,) trailing elements entry t folds: hc, hc - 1, ..., 1
+            need = hc - jnp.arange(hc, dtype=jnp.int32)
             in_chunk = need[None, :] <= n_seg[:, None]  # (C, hc)
-            src = jnp.clip(b[:, None] - need[None, :] + 1, 0, C - 1)
-            from_chunk = jax.tree.map(lambda s_: s_[src], ss)
+            # lane t reads row b - need + 1 >= a wherever in_chunk holds, so
+            # the padding before row 0 is only ever read on masked lanes
+            from_chunk = _row_windows(ss, b, hc)
             new_tail = jax.tree.map(
                 lambda fc, cd: jnp.where(_bc(in_chunk, fc), fc, cd[:, h0:]),
                 from_chunk,
@@ -931,12 +977,15 @@ class KeyedWindowStore:
             if self.horizon is not None:
                 # carry_ts rides the SAME ladder as carry: shifted old lane
                 # t + n_seg (-inf for new heads) on lanes the chunk can't
-                # fill, ``tss[src]`` where the trailing suffix fits — one
-                # extra lane view of the already-gathered ts_rows and one
-                # scatter, so the donation discipline holds for carry_ts too
-                ts_old = jnp.take_along_axis(ts_rows, old_t, axis=1)
+                # fill, the chunk's own timestamps where the trailing suffix
+                # fits — the same shifts of the already-gathered ts_rows,
+                # the same row slices of ``tss`` and one scatter, so the
+                # donation discipline holds for carry_ts too
+                ts_old = _shift_lanes(ts_rows, n_seg, h)
                 ts_old = jnp.where(row_new[:, None], -jnp.inf, ts_old)
-                ts_tail = jnp.where(in_chunk, tss[src], ts_old[:, h0:])
+                ts_tail = jnp.where(
+                    in_chunk, _row_windows(tss, b, hc), ts_old[:, h0:]
+                )
                 if h0:
                     cts1 = state["carry_ts"].at[head_scat, :h0].set(
                         ts_old[:, :h0], mode="drop"

@@ -1,8 +1,12 @@
 """Keyed store internals: ``KeyDirectory`` collision, LRU-eviction,
 TTL-expiry and negative-key edge cases; window-lane reset on slot reuse
-(no cross-tenant leakage) and dropped overflow rows; SWAG interop through
-the warm carry protocol (``export_states`` / ``adopt_states``); and
-``KeyedTelemetry`` per-key metrics with their state_dict round trip."""
+(no cross-tenant leakage) and dropped overflow rows; the carry refresh's
+lane shifts and row slices, bit-exact at every shift boundary and lowered
+without element-index gathers; SWAG interop through the warm carry
+protocol (``export_states`` / ``adopt_states``); and ``KeyedTelemetry``
+per-key metrics with their state_dict round trip."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -10,10 +14,11 @@ import numpy as np
 import pytest
 
 from repro.core import daba_lite, monoids
-from repro.core.keyed import KeyDirectory, KeyedWindowStore
+from repro.core.keyed import KeyDirectory, KeyedChunkedStream, KeyedWindowStore
+from repro.core.reference import per_key_horizon_reference, per_key_reference
 from repro.core.telemetry import KeyedTelemetry
 
-from keyed_cases import affine_vals, rng, tree_equal, val_list
+from keyed_cases import MONOID_CASES, affine_vals, rng, tree_equal, val_list
 
 
 # ---------------------------------------------------------------------------
@@ -130,6 +135,138 @@ def test_store_ttl_sweep_inside_update():
     )
     _, found = store.query(st, jnp.asarray([1, 2], jnp.int32))
     assert not bool(found[0]) and bool(found[1])
+
+
+# ---------------------------------------------------------------------------
+# Carry refresh: lane shifts and from-chunk row slices
+# ---------------------------------------------------------------------------
+
+# (window, chunk, slots, chunks), each chunk a list of (key, segment
+# length).  The lengths hit 1, 2**k - 1, 2**k, h - 1, h and > h where a
+# chunk holds them.  Each later key group takes reclaimed slots of evicted
+# keys, which never return, so the per-key oracles stay exact.
+REFRESH_LAYOUTS = {
+    # W <= C: h = 7, every lane can come from the chunk (h0 = 0)
+    "h0_zero": (8, 32, 10, [
+        list(zip(range(0, 7), [1, 3, 4, 6, 7, 9, 2])),
+        list(zip(range(0, 7), [9, 7, 6, 4, 3, 1, 2])),
+        list(zip(range(7, 14), [2, 7, 1, 9, 6, 4, 3])),
+        list(zip(range(7, 14), [6, 3, 9, 1, 4, 7, 2])),
+        list(zip(range(14, 21), [4, 1, 2, 3, 7, 6, 9])),
+    ]),
+    # W > C: h = 17, the chunk fills only the last 16 lanes (h0 = 1)
+    "h0_pos": (18, 16, 6, [
+        list(zip(range(0, 4), [1, 3, 4, 8])),
+        [(3, 16)],
+        list(zip(range(0, 4), [8, 4, 3, 1])),
+        list(zip(range(4, 7), [7, 1, 8])),
+        list(zip(range(4, 6), [15, 1])),
+        list(zip(range(7, 11), [2, 2, 4, 8])),
+    ]),
+}
+
+
+def _expected_lanes(m, hist, h):
+    """Lane t of a key's carry folds its last min(h - t, n) lifted elements
+    (older operand LEFT); its ``carry_ts`` lane holds the timestamp of the
+    (h - t)-th element from the last, -inf where there is none."""
+    lanes, lane_ts = [None] * h, [-np.inf] * h
+    acc = None
+    for need in range(1, h + 1):
+        if need <= len(hist):
+            t_e, e = hist[-need]
+            acc = e if acc is None else m.combine(e, acc)
+            lane_ts[h - need] = t_e
+        lanes[h - need] = acc
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *lanes), lane_ts
+
+
+@pytest.mark.parametrize("name", ["max_i32", "sum_i32", "affine_i32"])
+@pytest.mark.parametrize("horizon", [None, 0.75], ids=["count", "horizon"])
+@pytest.mark.parametrize("layout", sorted(REFRESH_LAYOUTS))
+def test_carry_refresh_bit_exact_at_shift_boundaries(layout, horizon, name):
+    """Per-row folds and the stored ``carry`` / ``carry_ts`` lanes after
+    every chunk equal the per-key oracles, for segment lengths on each bit
+    boundary of the lane shift, new keys on reclaimed slots, both static
+    lane splits and both window modes."""
+    W, C, S, chunks = REFRESH_LAYOUTS[layout]
+    make, gen = MONOID_CASES[name]
+    m = make()
+    hz = None if horizon is None else horizon * C
+    eng = KeyedChunkedStream(m, W, slots=S, chunk=C, horizon=hz)
+    store, h = eng.store, W - 1
+    st = eng.init_state()
+    all_keys, all_vals, all_ts, ys = [], [], [], []
+    hist: dict = {}
+    t_now = 0.0
+    for layout_c in chunks:
+        keys = np.concatenate([[k] * n for k, n in layout_c]).astype(np.int32)
+        keys = rng.permutation(keys)
+        assert keys.size == C
+        vals = gen(C)
+        ts = t_now + np.cumsum(rng.integers(0, 3, C)).astype(np.float32)
+        t_now = float(ts[-1])
+        st, y, _ = eng.process_chunk(
+            st, keys, vals, None if hz is None else jnp.asarray(ts)
+        )
+        ys.append(y)
+        all_keys.append(keys)
+        all_ts.append(ts)
+        all_vals += val_list(vals)
+        for k, v, t in zip(keys, val_list(vals), ts):
+            hist.setdefault(int(k), []).append((float(t), m.lift(v)))
+        live = sorted({k for k, _ in layout_c})
+        slot, found = store.directory.lookup(
+            st["dir"], jnp.asarray(live, jnp.int32)
+        )
+        assert bool(found.all())
+        for k, s in zip(live, np.asarray(slot)):
+            lanes, lane_ts = _expected_lanes(m, hist[k], h)
+            assert tree_equal(jax.tree.map(lambda c: c[s], st["carry"]), lanes)
+            if hz is not None:
+                assert np.asarray(st["carry_ts"][s]).tolist() == lane_ts
+    assert int(st["dir"]["n_evicted"]) > 0  # new heads took reclaimed slots
+    keys = np.concatenate(all_keys)
+    if hz is None:
+        ref = per_key_reference(m, keys, all_vals, W)
+    else:
+        ref = per_key_horizon_reference(
+            m, keys, all_vals, np.concatenate(all_ts), W, hz
+        )
+    got = jax.tree.map(lambda *parts: jnp.concatenate(parts), *ys)
+    assert tree_equal(got, ref)
+
+
+_GATHER = re.compile(
+    r'"stablehlo\.gather".*slice_sizes = array<i64: ([\d, ]+)>.*'
+    r"-> tensor<([\dx]+)x\w+>"
+)
+
+
+@pytest.mark.parametrize("name", ["max_i32", "sum_i32", "affine_i32"])
+def test_carry_refresh_lowers_without_lane_element_gathers(name):
+    """No (C, h) lane realignment of the refresh is an element-index
+    gather (``slice_sizes`` 1 on the lane axis): on a TPU those cost about
+    2,000x their bytes, while the row gathers of the same rows are cheap.
+    Lowered to StableHLO only; nothing compiles."""
+    C, W = 1024, 256
+    store = KeyedWindowStore(MONOID_CASES[name][0](), W, slots=4096,
+                             horizon=10.0)
+    spec = jax.ShapeDtypeStruct
+    xs = jax.tree.map(lambda x: spec(x.shape, x.dtype),
+                      MONOID_CASES[name][1](C))
+    text = jax.jit(store.update_chunk).lower(
+        jax.eval_shape(store.init_state), spec((C,), jnp.int32), xs,
+        spec((C,), jnp.float32), spec((C,), bool),
+    ).as_text()
+    gathers = [
+        (tuple(int(s) for s in g[0].split(",")),
+         tuple(int(d) for d in g[1].split("x")))
+        for g in _GATHER.findall(text)
+    ]
+    lane_sized = [g for g in gathers if g[1][:2] == (C, store.h)]
+    assert lane_sized  # the carry row gathers are found at all
+    assert all(max(sizes) > 1 for sizes, _ in lane_sized), lane_sized
 
 
 # ---------------------------------------------------------------------------

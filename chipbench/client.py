@@ -5,38 +5,26 @@ Started by ``run.py``; never imports JAX.  Protocol on its standard
 streams, one JSON document per line:
 
 1. in: the job (``root``, ``seed``, ``generator``, ``mix``, ``tenants``);
-   the client builds the streams and calls the mix's driver's
+   the client builds the streams with the generator the configuration
+   names and calls the mix's driver's
    ``prepare(job, streams)``, then writes ``{"ready": true}``;
 2. in: ``{"url", "t_open", "t_close"}`` (``time.monotonic`` seconds, the
    clock the server process reads too); the client calls the traffic driver's
    ``run(plan, go)`` and writes its records as the last line.
 
-A driver is ``chipbench/traffic/<mix["driver"]>.py``, found by name.
+A driver is ``chipbench/traffic/<mix["driver"]>.py`` and a generator
+``chipbench/generators/<generator["name"]>.py``, each found by name
+(:mod:`chipbench.byname`).
 """
 
 from __future__ import annotations
 
 import http.client
-import importlib.util
 import json
 import pathlib
 import sys
 import time
 from urllib.parse import urlparse
-
-
-def load_module(path: pathlib.Path, name: str):
-    """The Python file ``path`` as a module named ``name``."""
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def load_driver(root: pathlib.Path, name: str):
-    return load_module(
-        pathlib.Path(root) / "chipbench" / "traffic" / f"{name}.py",
-        f"chipbench_driver_{name}")
 
 
 def request(url: str, method: str, path: str, body: bytes = None,
@@ -77,11 +65,12 @@ def sleep_until(t: float) -> None:
 def main() -> int:
     root = pathlib.Path(__file__).resolve().parents[1]
     sys.path.insert(0, str(root))
-    from chipbench import gen
+
+    from chipbench import byname
 
     job = json.loads(sys.stdin.readline())
-    driver = load_driver(job["root"], job["mix"]["driver"])
-    streams = gen.tenant_streams(job["seed"], job["generator"])
+    driver = byname.driver(job["root"], job["mix"]["driver"])
+    streams = byname.tenant_streams(job["root"], job["seed"], job["generator"])
     plan = driver.prepare(job, streams)
     print(json.dumps({"ready": True}), flush=True)
     line = sys.stdin.readline()

@@ -13,8 +13,9 @@ place with one stated guarantee broken:
 * ``stale``: every tenant's last acknowledged batch is missing, as from a
   read that answers before the final drain is synced (breaks "every
   acknowledged row is queryable after flush()");
-* ``int16``: the folds wrap at 16 bits, the integer type below the
-  stated int32.
+* ``narrow``: each running fold is rounded to the type below the stated
+  one, as the fold's file says (``narrow``: int16 for int32, float16 for
+  float32).
 
 One JSON line per seed: the program's checks and each control's.  The
 benchmark's own runs never run this.
@@ -33,14 +34,14 @@ sys.path.insert(0, str(ROOT))
 from chipbench import reference, run  # noqa: E402
 
 
-def _answers(rec: dict, acked: list, bits: int) -> list:
+def _answers(rec: dict, acked: list, narrow: bool) -> list:
     cfg = rec["config"]["service"]
     out = []
     for i, stream in enumerate(rec["streams"]):
         k, t, x = stream.rows(0, acked[i])
         out.append(reference.key_folds(
-            cfg["monoid"], k, t, x, rec["queried"][i], window=cfg["window"],
-            horizon=cfg["horizon"], bits=bits))
+            rec["fold"], k, t, x, rec["queried"][i], window=cfg["window"],
+            horizon=cfg["horizon"], narrow=narrow))
     return out
 
 
@@ -51,14 +52,15 @@ def stale(rec: dict) -> list:
     for i, a in enumerate(rec["acked_rows"]):
         mine = b[(b[:, 0] == i) & (b[:, 4] == 200)]
         acked.append(a - int(mine[-1, 6]) if len(mine) else a)
-    return _answers(rec, acked, 32)
+    return _answers(rec, acked, narrow=False)
 
 
-def int16(rec: dict) -> list:
-    return _answers(rec, rec["acked_rows"], 16)
+def narrow(rec: dict) -> list:
+    """The reference in the type below the stated one."""
+    return _answers(rec, rec["acked_rows"], narrow=True)
 
 
-CONTROLS = {"stale": stale, "int16": int16}
+CONTROLS = {"stale": stale, "narrow": narrow}
 
 
 def main(argv=None) -> int:

@@ -8,7 +8,9 @@ One process holds the chip: it builds the :class:`AnalyticsService` and
 its :class:`ServiceHTTPServer` on their threads.  Traffic comes from
 ``chipbench/client.py``, a second process that never imports JAX and
 posts over HTTP.  Everything is found by name from ``BENCHMARK.json``:
-the cell's configuration (``chipbench/configs/<config>.json``), its mix
+the cell's configuration (``chipbench/configs/<config>.json``) with the
+data generator it names (``chipbench/generators/<name>.py``) and the
+reference fold of its monoid (``chipbench/folds/<monoid>.py``), its mix
 (``chipbench/traffic/<mix>.json`` and the traffic driver it names) and each
 metric (``chipbench/metrics/<metric>.py``).
 
@@ -57,7 +59,7 @@ import numpy as np  # noqa: E402
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-from chipbench import client, gen, reference, window  # noqa: E402
+from chipbench import byname, client, reference, window  # noqa: E402
 
 # what the check after the window queries, per tenant: the hottest keys
 # of its last CHECK_RECENT rows, a sample of the window's keys, ids never sent
@@ -104,10 +106,7 @@ def read_metrics(root: pathlib.Path, specs: list, rec: dict) -> dict:
     reader that finds nothing to read returns None and is left out."""
     out = {}
     for m in specs:
-        mod = client.load_module(
-            root / "chipbench" / "metrics" / f"{m['name']}.py",
-            f"chipbench_metric_{m['name'].replace('.', '_')}")
-        v = mod.read(rec)
+        v = byname.metric(root, m["name"]).read(rec)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
@@ -359,11 +358,12 @@ def run_cell(root: pathlib.Path, spec: dict, *, seed: int, seconds: float,
     from repro.service import AnalyticsService, ServiceConfig, ServiceHTTPServer
 
     svc_cfg = ServiceConfig(**config["service"])
+    fold = byname.fold(root, svc_cfg.monoid)
     g = config["generator"]
     names = [f"tenant-{i}" for i in range(g["tenants"])]
     prefill = mix["prefill_rows_per_tenant"]
     batch = mix["batch_rows"]
-    streams = gen.tenant_streams(seed, g)
+    streams = byname.tenant_streams(root, seed, g)
     job = {"root": str(root), "seed": seed, "generator": g, "mix": mix,
            "tenants": names, "chunk": svc_cfg.chunk, "start_row": prefill,
            "seconds": seconds}
@@ -493,7 +493,7 @@ def run_cell(root: pathlib.Path, spec: dict, *, seed: int, seconds: float,
 
     # -- the reference, with the program's state freed ----------------------
     log("service stopped; comparing with the reference")
-    rec["streams"] = streams
+    rec["streams"], rec["fold"] = streams, fold
     if answers is not None:
         rec["got"] = answers(rec)
     checks = compare(rec)
@@ -541,18 +541,24 @@ def query_check_keys(url, streams, names, acked, prefill, key_bits, seed):
     return got, queried
 
 
+def answer(found, fold) -> tuple:
+    """An answer as compared, exactly: ``(found, the fold as a tuple of
+    numbers)``, a scalar fold being a tuple of one."""
+    return bool(found), tuple(np.ravel(fold).tolist())
+
+
 def compare(rec: dict) -> dict:
     """The checked numbers, each compared with its limit in ``LIMITS``."""
     cfg = rec["config"]["service"]
     mismatched = 0
     for i, stream in enumerate(rec["streams"]):
         k, t, x = stream.rows(0, rec["acked_rows"][i])
-        want = reference.key_folds(cfg["monoid"], k, t, x, rec["queried"][i],
+        want = reference.key_folds(rec["fold"], k, t, x, rec["queried"][i],
                                    window=cfg["window"],
                                    horizon=cfg["horizon"])
         for key in rec["queried"][i]:
             g = rec["got"][i].get(key)
-            if g is None or (bool(g[0]), g[1]) != want[key]:
+            if g is None or answer(*g) != answer(*want[key]):
                 mismatched += 1
     per = rec["stats_end"]["per_tenant"]
     missing = sum(max(0, a - per[n]["queryable_rows"])
@@ -594,8 +600,9 @@ def make_result(root, spec, rec, devs, trace) -> dict:
     return result
 
 
-def counts_line(rec: dict) -> dict:
-    """Counts worth keeping that are not metrics."""
+def counts_line(root: pathlib.Path, rec: dict) -> dict:
+    """Counts worth keeping that are not metrics (``chunk_fill`` is the
+    metric's own reader, read in every cell)."""
     lat = np.asarray(rec["client"]["lateness_s"], np.float64)
     per = rec["stats_close"]["per_tenant"]
     out = {
@@ -614,10 +621,27 @@ def counts_line(rec: dict) -> dict:
             * 1e3},
         "chunks_in_window": (rec["stats_close"]["chunks"]
                              - rec["stats_open"]["chunks"]),
+        "chunk_fill": byname.metric(root, "chunk_fill").read(rec),
+        "sender_busy_share": sender_busy_share(rec),
         "stats_reads": rec["client"]["stats_reads"],
         "host": rec["host"],
     }
     return out
+
+
+def sender_busy_share(rec: dict) -> dict:
+    """The client's POSTs due in the window, summed over them and over the
+    window's length, in two parts: ``due_to_sent``, the client's own
+    (``saturate``: making and encoding the rows; ``open_loop``: the
+    sender's lateness), and ``sent_to_replied``, the server's handling of
+    the POST.  Summed over senders, so a part can pass 1 where several
+    post at once; one sender whose two parts add to well under 1 waited
+    on the service's backlog."""
+    b = rec["batches"]
+    b = b[window.due_in_window(b, rec)]
+    return {"due_to_sent": float((b[:, 2] - b[:, 1]).sum()) / rec["seconds"],
+            "sent_to_replied": float((b[:, 3] - b[:, 2]).sum())
+            / rec["seconds"]}
 
 
 def new_key_share(rec: dict) -> dict:
@@ -659,7 +683,7 @@ def main(argv=None, *, root: pathlib.Path = ROOT,
     except NoChip as e:
         print(f"chipbench: {e}", file=sys.stderr)
         return EXIT_NO_CHIP
-    print(json.dumps({"counts": counts_line(rec)}), flush=True)
+    print(json.dumps({"counts": counts_line(root, rec)}), flush=True)
     for k, v in result["checks"].items():
         print(f"check {k} {v['value']} limit {v['limit']}", file=sys.stderr)
     sys.stderr.flush()

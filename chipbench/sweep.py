@@ -23,7 +23,7 @@ import sys
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-from chipbench import run, window  # noqa: E402
+from chipbench import byname, run, window  # noqa: E402
 
 
 def summary(rec: dict, rate: float) -> dict:
@@ -36,11 +36,7 @@ def summary(rec: dict, rate: float) -> dict:
            "drained_events_per_s": float(b[(vis > t0) & (vis <= t1), 6].sum())
            / rec["seconds"],
            "backlog_rows_at_close": rec["stats_close"]["pending_rows"],
-           "chunk_fill_pct": None}
-    a, c = rec["stats_open"], rec["stats_close"]
-    if c["chunks"] > a["chunks"]:
-        out["chunk_fill_pct"] = 100.0 * (c["drained_rows"] - a["drained_rows"]) \
-            / ((c["chunks"] - a["chunks"]) * rec["chunk"])
+           "chunk_fill_pct": byname.metric(ROOT, "chunk_fill").read(rec)}
     for label, sel in (("first_half", ok & (b[:, 1] < half)),
                        ("second_half", ok & (b[:, 1] >= half))):
         out[label] = {"p50_ms": window.percentile(lat[sel], 50),

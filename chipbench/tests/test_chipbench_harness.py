@@ -17,7 +17,7 @@ import pytest
 REPO = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(REPO))
 
-from chipbench import control, run  # noqa: E402
+from chipbench import control, run, window  # noqa: E402
 
 TINY_SERVICE = dict(slots=16384, window=8, chunk=128, max_batch=32,
                     rollup=False)
@@ -119,9 +119,74 @@ def test_finds_a_config_mix_driver_metric_and_cell_added_as_files(tmp_path):
     assert [m["name"] for m in spec["per_layer"]] == ["twice_seconds"]
     got = run.read_metrics(root, spec["per_layer"], {"seconds": 3.0})
     assert got == {"twice_seconds": {"value": 6.0, "unit": "s"}}
-    from chipbench import client
+    from chipbench import byname
 
-    assert client.load_driver(root, "trickle").run(None, None)["batches"] == []
+    assert byname.driver(root, "trickle").run(None, None)["batches"] == []
+
+
+def test_finds_a_deployment_with_its_generator_and_fold_by_name(tmp_path):
+    """A deployment that is not NEXmark bids, TSBS devops cpu-only under
+    a sliding cpu-max-all, goes in as files and entries alone; its rows
+    are checked against the reference without starting the service."""
+    root = tiny_root(tmp_path)
+    cb = root / "chipbench"
+    hosts, log_s, readings, horizon = 50, 10, 360, 3600.0
+    cfg = json.loads((cb / "configs" / "bids-max.json").read_text())
+    cfg["service"].update(monoid="max", value_dtype="f32", window=readings,
+                          horizon=horizon)
+    cfg["generator"] = {"name": "tsbs_cpu", "tenants": 2, "hosts": hosts,
+                        "log_interval_s": log_s}
+    (cb / "configs" / "tsbs-cpu-max.json").write_text(json.dumps(cfg))
+    (cb / "traffic" / "hour.json").write_text(json.dumps(
+        {"driver": "saturate", "prefill_rows_per_tenant": 360 * hosts,
+         "batch_rows": 32, "backlog_chunks": [2, 4], "stats_every_s": 0.1}))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tsbs-cpu-max", "source": "a test",
+                             "file": "chipbench/configs/tsbs-cpu-max.json",
+                             "reduced": [], "why": "a test"})
+    bench["workloads"].append({"name": "tsbs-cpu-max.hour",
+                               "config": "tsbs-cpu-max", "traffic": "hour",
+                               "chips": 1, "why": "a test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+    from chipbench import byname, reference
+
+    spec = run.load_cell(root, "tsbs-cpu-max.hour")
+    gen_cfg, svc = spec["config"]["generator"], spec["config"]["service"]
+    streams = byname.tenant_streams(root, 2**31 + 77, gen_cfg)
+    fold = byname.fold(root, svc["monoid"])
+    # an hour and 7 intervals, and 3 hosts of the next: the window of 360
+    # readings binds for every host
+    acked = [spec["mix"]["prefill_rows_per_tenant"] + 7 * hosts + 3] * 2
+    got, queried = [], []
+    for s in streams:
+        k, t, x = s.rows(0, acked[0])
+        assert x.shape == (acked[0], 10) and k.max() == hosts - 1
+        answers = {}
+        for h in range(hosts):  # the fold, by whole arrays
+            mine = np.flatnonzero(k == h)[-readings:]
+            mine = mine[t[mine] > t[mine[-1]] - horizon]
+            answers[h] = (True, x[mine].max(axis=0).tolist())
+        answers[hosts + 3] = (False, [float(fold.IDENTITY)] * 10)
+        got.append(answers)
+        queried.append(sorted(answers))
+    per = {f"tenant-{i}": {"queryable_rows": a, "dropped_rows": 0,
+                           "shed_rows": 0, "throttled_rows": 0}
+           for i, a in enumerate(acked)}
+    rec = {"config": spec["config"], "streams": streams, "fold": fold,
+           "acked_rows": acked, "queried": queried, "got": got,
+           "names": list(per), "stats_end": {"per_tenant": per},
+           "flushed": True, "batches": np.zeros((0, 8)),
+           "queries": np.zeros((0, 5)), "visible": np.zeros(0)}
+    assert run.compare(rec) == dict.fromkeys(run.LIMITS, 0)
+    narrowed = run.compare(dict(rec, got=control.narrow(rec)))
+    assert narrowed["mismatched_folds"] == 2 * hosts
+    # the generator is named, never defaulted
+    with pytest.raises(FileNotFoundError):
+        byname.tenant_streams(root, 1, dict(gen_cfg, name="tsbs_cpus"))
+    with pytest.raises(KeyError):
+        byname.tenant_streams(root, 1, {"tenants": 1, "hosts": 2,
+                                        "log_interval_s": 10})
 
 
 def _plant(monkeypatch, fault: str) -> None:
@@ -156,7 +221,8 @@ def _plant(monkeypatch, fault: str) -> None:
         monkeypatch.setattr(AnalyticsService, "query", query)
 
 
-CONTROLS = {"control_stale": control.stale, "control_int16": control.int16}
+# the bids folds' narrower type is int16, hence the case's name
+CONTROLS = {"control_stale": control.stale, "control_int16": control.narrow}
 
 
 @pytest.mark.parametrize("case", ["sound", "state_unchanged", "half_batch",
@@ -178,5 +244,18 @@ def test_correct_fails_each_fault_and_the_control(case, tmp_path,
     if case == "sound":
         assert checks == dict.fromkeys(run.LIMITS, 0)
         assert np.isfinite(rec["visible"]).all()
+        counts = run.counts_line(root, rec)
+        # one saturate sender, one POST in flight at a time: its two busy
+        # parts fit between the window's opening and its last reply
+        b = rec["batches"]
+        last_reply = b[window.due_in_window(b, rec), 3].max()
+        busy = counts["sender_busy_share"]
+        assert busy["due_to_sent"] > 0 and busy["sent_to_replied"] > 0
+        assert (busy["due_to_sent"] + busy["sent_to_replied"]) \
+            * rec["seconds"] <= last_reply - rec["t_open"]
+        assert 0 < counts["chunk_fill"] <= 100.0
+        assert counts["chunk_fill"] == run.read_metrics(
+            root, [{"name": "chunk_fill", "unit": "%"}],
+            rec)["chunk_fill"]["value"]
     else:
         assert checks["mismatched_folds"] > 0
